@@ -60,6 +60,7 @@ from repro.core.txn import make_batch
 from repro.core.workloads import make_ycsb
 from repro.obs import (FlightRecorder, PhaseTracer, stitch_chrome_trace,
                        validate_chrome_trace)
+from repro.runtime import setup_compile_cache
 from repro.service import TxnService
 from repro.service.txn_service import LATENCY_CLASSES
 
@@ -419,5 +420,6 @@ def run(quick: bool = False, trace: bool = False,
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     run(quick="--quick" in sys.argv, trace="--trace" in sys.argv,
         flight="--flight" in sys.argv)

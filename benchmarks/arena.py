@@ -28,6 +28,7 @@ from benchmarks.common import write_csv
 from repro.arena import (PROTOCOL_NAMES, arena_matrix, run_gauntlet,
                          run_matrix)
 from repro.obs import MetricsRegistry
+from repro.runtime import setup_compile_cache
 
 
 def markdown_pivot(rows: List[Dict]) -> str:
@@ -113,6 +114,7 @@ def run(quick: bool = False, iters: int = 2, seed: int = 0,
 
 
 def main() -> None:
+    setup_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--quick", action="store_true",
                     help="smaller store/batches, fewer theta points")

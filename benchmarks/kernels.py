@@ -18,6 +18,7 @@ import numpy as np
 from benchmarks.common import time_fn, write_csv
 from repro.kernels import ops
 from repro.kernels.mvcc_resolve import default_interpret
+from repro.runtime import setup_compile_cache
 
 INF = np.iinfo(np.int32).max
 
@@ -65,4 +66,5 @@ def run(interpret: Optional[bool] = None) -> list:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     run()

@@ -9,17 +9,11 @@
   exec threads -> execution-wavefront vector lanes == batch size (every
                   wave is one fused data-parallel step over all ready txns).
 
-Needs >1 host device for cc_shards > 1: when run as a script it re-execs
-itself with --xla_force_host_platform_device_count=8 (never set globally).
+cc_shards > 1 take their devices from ``jax.devices()``: the chips that
+exist, or — under ``JAX_PLATFORMS=cpu`` — 8 virtual CPU devices, which
+``main`` asks for before JAX starts.
 """
 from __future__ import annotations
-
-import os
-import sys
-
-if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
 
 import jax
 import numpy as np
@@ -27,6 +21,7 @@ import numpy as np
 from benchmarks.common import time_fn, write_csv
 from repro.core.engine import BohmEngine
 from repro.core.workloads import gen_ycsb_batch, make_microbench
+from repro.runtime import cc_mesh, force_cpu_devices, setup_compile_cache
 
 N_RECORDS = 1_000_000
 OPS = 10
@@ -40,7 +35,7 @@ def run(cc_shards=(1, 2, 4, 8), batch_sizes=(256, 512, 1024, 2048)) -> list:
     for n_cc in cc_shards:
         if n_cc > n_dev:
             continue
-        mesh = jax.make_mesh((n_cc,), ("cc",)) if n_cc > 1 else None
+        mesh = cc_mesh(n_cc) if n_cc > 1 else None
         for batch_size in batch_sizes:
             eng = BohmEngine(N_RECORDS, wl, mesh=mesh)
             batch = gen_ycsb_batch(rng, batch_size, N_RECORDS, theta=0.0,
@@ -58,5 +53,11 @@ def run(cc_shards=(1, 2, 4, 8), batch_sizes=(256, 512, 1024, 2048)) -> list:
     return rows
 
 
-if __name__ == "__main__":
+def main() -> None:
+    force_cpu_devices(8)
+    setup_compile_cache()
     run()
+
+
+if __name__ == "__main__":
+    main()

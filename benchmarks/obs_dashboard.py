@@ -41,6 +41,7 @@ from repro.obs import (FlightRecorder, HealthMonitor, LifecycleAuditor,
                        PhaseTracer, run_metadata, stitch_chrome_trace,
                        validate_chrome_trace)
 from repro.obs.lifecycle import AUDIT_STATE_NAMES
+from repro.runtime import setup_compile_cache
 from repro.service import TxnService
 
 R = 64          # few records...
@@ -208,6 +209,7 @@ def report(out: dict) -> None:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="short stream (CI smoke)")

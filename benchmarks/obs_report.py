@@ -32,6 +32,7 @@ from repro.core.engine import BohmEngine
 from repro.core.txn import Workload, make_batch
 from repro.obs import (FlightRecorder, PhaseTracer, run_metadata,
                        stitch_chrome_trace, validate_chrome_trace)
+from repro.runtime import setup_compile_cache
 from repro.service import TxnService
 
 T, OPS, R = 64, 4, 256
@@ -163,6 +164,7 @@ def report(out: dict) -> None:
 
 
 def main():
+    setup_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
                     help="short stream (CI smoke)")

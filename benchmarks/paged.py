@@ -38,6 +38,7 @@ from benchmarks.spill import (BATCH, COLD_N, HOT_N, N_BATCHES, N_RECORDS,
                               OPS, _hotset_batch, _run_stream)
 from repro.core.engine import BohmEngine
 from repro.core.workloads import make_ycsb
+from repro.runtime import setup_compile_cache
 
 RING_SLOTS = 4
 K_MAX = 16
@@ -125,4 +126,5 @@ def run(quick: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     run(quick="--quick" in sys.argv)

@@ -17,8 +17,9 @@ add work, so pipelined >= barriered at equal batch size is the expected
 (and asserted-by-eyeball) outcome; on TPU the same schedule additionally
 overlaps CC compute with exec compute on separate cores.
 
-Needs >1 host device for mesh shards: as a script it re-execs itself with
---xla_force_host_platform_device_count=4 (never set globally).
+Mesh shards take their devices from ``jax.devices()``: the chips that
+exist, or — under ``JAX_PLATFORMS=cpu`` — 4 virtual CPU devices, which
+``main`` asks for before JAX starts.
 """
 from __future__ import annotations
 
@@ -26,16 +27,13 @@ import os
 import sys
 import time
 
-if __name__ == "__main__" and "XLA_FLAGS" not in os.environ:
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    os.execv(sys.executable, [sys.executable] + sys.argv)
-
 import jax
 import numpy as np
 
 from benchmarks.common import write_csv
 from repro.core.engine import BohmEngine
 from repro.core.workloads import gen_ycsb_batch, make_ycsb
+from repro.runtime import cc_mesh, force_cpu_devices, setup_compile_cache
 from repro.service import TxnService
 
 N_RECORDS = 8192
@@ -54,7 +52,7 @@ def bench_shards(n_shards: int, rng, n_batches: int,
     # stay on the (bit-identical) logical substrate there
     use_mesh = 1 < n_shards <= min(jax.device_count(),
                                    os.cpu_count() or 1)
-    mesh = jax.make_mesh((n_shards,), ("cc",)) if use_mesh else None
+    mesh = cc_mesh(n_shards) if use_mesh else None
     batches = [gen_ycsb_batch(rng, BATCH, N_RECORDS, theta=0.6,
                               mix="10rmw") for _ in range(n_batches + 1)]
     svcs, times = {}, {}
@@ -111,5 +109,11 @@ def run(quick: bool = False) -> list:
     return rows
 
 
-if __name__ == "__main__":
+def main() -> None:
+    force_cpu_devices(4)
+    setup_compile_cache()
     run(quick="--quick" in sys.argv)
+
+
+if __name__ == "__main__":
+    main()

@@ -2,13 +2,13 @@
 
     PYTHONPATH=src python -m benchmarks.run [--quick]
 
-  microbench  Fig 4   CC-shard scalability (subprocess: 8 host devices)
+  microbench  Fig 4   CC-shard scalability over the cc mesh
   ycsb        Fig 5-7 Bohm vs 2PL/SI/OCC, low/high contention + theta sweep
   smallbank   Fig 8-10 full mix + read-only vs contention
   snapshot    Fig 9/10 scenario: update stream + pinned snapshot scans
               through the version ring (occupancy, GC, scan survival)
   pipeline    §3/Fig 3 overlap: TxnService update stream at 1/2/4 store
-              shards, pipelined vs barriered (subprocess: 4 host devices)
+              shards, pipelined vs barriered
   admission   conflict-aware admission: merged CC epochs + exec-exec
               overlap vs the barriered baseline, hot/cold skewed streams
   spill       hierarchical version storage: fixed-K drop vs spill vs
@@ -23,9 +23,11 @@
               workload matrix at matched batch sizes + anomaly gauntlet
               (headline claim + serializability verdicts in one twin)
 
-Roofline terms for the 40 (arch x shape) cells come from the dry-run
-artifact (see repro/launch/dryrun.py and repro/launch/roofline.py) and are
-summarised in EXPERIMENTS.md; they are not re-derived here.
+Every suite runs in a process of its own and this parent never imports
+JAX, so a suite that needs the chip always finds it free. Each suite is
+started as ``python -m benchmarks.<name> [--quick]`` and sets itself up
+(compile cache; under ``JAX_PLATFORMS=cpu`` the mesh suites ask for
+virtual CPU devices, on an accelerator they use the devices that exist).
 """
 from __future__ import annotations
 
@@ -35,71 +37,22 @@ import subprocess
 import sys
 from pathlib import Path
 
+ROOT = Path(__file__).resolve().parents[1]
 
-def bench_microbench():
-    # needs its own process: forces 8 host devices before jax init
-    root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{root / 'src'}:{root}"
-    subprocess.run(
-        [sys.executable, str(Path(__file__).parent / "microbench.py")],
-        check=True, cwd=str(root), env=env)
-
-
-def bench_ycsb(quick: bool = False):
-    from benchmarks import ycsb
-    ycsb.run(sweep_theta=not quick)
-
-
-def bench_smallbank(quick: bool = False):
-    from benchmarks import smallbank
-    smallbank.run(sweep_customers=not quick)
-
-
-def bench_snapshot():
-    from benchmarks import snapshot
-    snapshot.run()
-
-
-def bench_pipeline(quick: bool = False):
-    # needs its own process: forces 4 host devices before jax init
-    root = Path(__file__).resolve().parents[1]
-    env = dict(os.environ)
-    env["PYTHONPATH"] = f"{root / 'src'}:{root}"
-    cmd = [sys.executable, str(Path(__file__).parent / "pipeline.py")]
-    if quick:
-        cmd.append("--quick")
-    subprocess.run(cmd, check=True, cwd=str(root), env=env)
-
-
-def bench_admission(quick: bool = False):
-    from benchmarks import admission
-    admission.run(quick)
-
-
-def bench_spill(quick: bool = False):
-    from benchmarks import spill
-    spill.run(quick)
-
-
-def bench_paged(quick: bool = False):
-    from benchmarks import paged
-    paged.run(quick)
-
-
-def bench_kernels():
-    from benchmarks import kernels
-    kernels.run()
-
-
-def bench_serving():
-    from benchmarks import serving
-    serving.run()
-
-
-def bench_arena(quick: bool = False):
-    from benchmarks import arena
-    arena.run(quick=quick)
+# suite (= module name under benchmarks/) -> title
+SUITES = {
+    "microbench": "microbench (Fig 4)",
+    "ycsb": "ycsb (Figs 5-7)",
+    "smallbank": "smallbank (Figs 8-10)",
+    "snapshot": "snapshot (Figs 9/10 scenario)",
+    "pipeline": "pipeline (Fig 3 overlap)",
+    "admission": "admission (conflict-aware scheduler)",
+    "spill": "spill (hierarchical version storage)",
+    "paged": "paged (page-slab physical storage)",
+    "kernels": "kernels",
+    "serving": "serving",
+    "arena": "arena (cross-protocol matrix + gauntlet)",
+}
 
 
 def main() -> None:
@@ -107,49 +60,25 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true",
                     help="skip the slow sweep dimensions")
     ap.add_argument("--only", default=None,
-                    help="comma-separated subset: microbench,ycsb,"
-                         "smallbank,snapshot,pipeline,admission,spill,"
-                         "paged,kernels,serving,arena")
+                    help="comma-separated subset: " + ",".join(SUITES))
     args = ap.parse_args()
-    only = set(args.only.split(",")) if args.only else None
-
-    def want(name):
-        return only is None or name in only
-
-    if want("microbench"):
-        print("== microbench (Fig 4) ==", flush=True)
-        bench_microbench()
-    if want("ycsb"):
-        print("== ycsb (Figs 5-7) ==", flush=True)
-        bench_ycsb(args.quick)
-    if want("smallbank"):
-        print("== smallbank (Figs 8-10) ==", flush=True)
-        bench_smallbank(args.quick)
-    if want("snapshot"):
-        print("== snapshot (Figs 9/10 scenario) ==", flush=True)
-        bench_snapshot()
-    if want("pipeline"):
-        print("== pipeline (Fig 3 overlap) ==", flush=True)
-        bench_pipeline(args.quick)
-    if want("admission"):
-        print("== admission (conflict-aware scheduler) ==", flush=True)
-        bench_admission(args.quick)
-    if want("spill"):
-        print("== spill (hierarchical version storage) ==", flush=True)
-        bench_spill(args.quick)
-    if want("paged"):
-        print("== paged (page-slab physical storage) ==", flush=True)
-        bench_paged(args.quick)
-    if want("kernels"):
-        print("== kernels ==", flush=True)
-        bench_kernels()
-    if want("serving"):
-        print("== serving ==", flush=True)
-        bench_serving()
-    if want("arena"):
-        print("== arena (cross-protocol matrix + gauntlet) ==",
-              flush=True)
-        bench_arena(args.quick)
+    only = args.only.split(",") if args.only else list(SUITES)
+    unknown = [n for n in only if n not in SUITES]
+    if unknown:
+        ap.error(f"unknown suite(s): {','.join(unknown)}")
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [str(ROOT / "src"), str(ROOT)]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    for name, title in SUITES.items():
+        if name not in only:
+            continue
+        print(f"== {title} ==", flush=True)
+        # each module's __main__ does its own set-up and reads --quick
+        cmd = [sys.executable, "-m", f"benchmarks.{name}"]
+        if args.quick:
+            cmd.append("--quick")
+        subprocess.run(cmd, check=True, cwd=str(ROOT), env=env)
 
 
 if __name__ == "__main__":

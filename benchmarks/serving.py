@@ -11,6 +11,7 @@ import numpy as np
 from benchmarks.common import write_csv
 from repro.configs import get_config
 from repro.models import init_params
+from repro.runtime import setup_compile_cache
 from repro.serving.engine import ServeEngine
 
 
@@ -54,4 +55,5 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     run()

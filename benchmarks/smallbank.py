@@ -12,6 +12,8 @@ live (the workload-logic abort path, distinct from CC aborts).
 """
 from __future__ import annotations
 
+import sys
+
 import jax.numpy as jnp
 import numpy as np
 
@@ -19,6 +21,7 @@ from benchmarks.common import write_csv
 from repro.arena import ArenaCell, make_protocols, run_cell
 from repro.core.workloads import gen_smallbank_batch, make_smallbank
 from repro.obs import MetricsRegistry
+from repro.runtime import setup_compile_cache
 
 BATCH = 2048
 N_BATCHES = 4
@@ -59,4 +62,5 @@ def run(sweep_customers: bool = True) -> list:
 
 
 if __name__ == "__main__":
-    run()
+    setup_compile_cache()
+    run(sweep_customers="--quick" not in sys.argv)

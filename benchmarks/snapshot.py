@@ -33,6 +33,7 @@ from repro.core.engine import BohmEngine
 from repro.core.workloads import (gen_scan_batch, gen_smallbank_batch,
                                   gen_ycsb_batch, make_smallbank,
                                   make_ycsb)
+from repro.runtime import setup_compile_cache
 
 N_RECORDS = 8192
 BATCH = 512
@@ -104,4 +105,5 @@ def run() -> list:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     run()

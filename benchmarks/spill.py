@@ -48,6 +48,7 @@ from benchmarks.common import write_csv
 from repro.core.engine import BohmEngine
 from repro.core.txn import make_batch
 from repro.core.workloads import make_ycsb
+from repro.runtime import setup_compile_cache
 
 N_RECORDS = 8192
 HOT_N = 512          # stable hot set: ~2 updates/record/batch
@@ -175,4 +176,5 @@ def run(quick: bool = False) -> list:
 
 
 if __name__ == "__main__":
+    setup_compile_cache()
     run(quick="--quick" in sys.argv)

@@ -11,12 +11,15 @@ PR-standard JSON twin (``{"meta": ..., "rows": [...]}``) via
 """
 from __future__ import annotations
 
+import sys
+
 import numpy as np
 
 from benchmarks.common import write_csv
 from repro.arena import ArenaCell, make_protocols, run_cell
 from repro.core.workloads import gen_ycsb_batch, make_ycsb
 from repro.obs import MetricsRegistry
+from repro.runtime import setup_compile_cache
 
 N_RECORDS = 262_144
 BATCH = 1024
@@ -52,4 +55,5 @@ def run(sweep_theta: bool = True, num_records: int = N_RECORDS,
 
 
 if __name__ == "__main__":
-    run()
+    setup_compile_cache()
+    run(sweep_theta="--quick" not in sys.argv)

@@ -52,23 +52,25 @@ ALL_WORKLOADS = {c.name: c for c in [
     YCSB_HIGH_2RMW8R, SMALLBANK_HIGH, SMALLBANK_READONLY]}
 
 
-def build(cfg: BohmWorkloadConfig, seed: int = 0, mesh=None
-          ) -> Tuple[BohmEngine, Callable]:
-    """Returns (engine, batch_gen(rng) -> TxnBatch)."""
+def build(cfg: BohmWorkloadConfig, seed: int = 0, mesh=None,
+          **engine_kw) -> Tuple[BohmEngine, Callable]:
+    """Returns (engine, batch_gen() -> TxnBatch). ``engine_kw`` go to
+    ``BohmEngine`` (storage layout, shard count, ...)."""
     rng = np.random.default_rng(seed)
     if cfg.kind == "microbench":
         wl = make_microbench()
-        eng = BohmEngine(cfg.num_records, wl, mesh=mesh)
+        eng = BohmEngine(cfg.num_records, wl, mesh=mesh, **engine_kw)
         gen = lambda: gen_ycsb_batch(rng, cfg.batch_size, cfg.num_records,
                                      theta=0.0, mix="10rmw")
     elif cfg.kind == "ycsb":
         wl = make_ycsb(payload_words=cfg.payload_words)
-        eng = BohmEngine(cfg.num_records, wl, mesh=mesh)
+        eng = BohmEngine(cfg.num_records, wl, mesh=mesh, **engine_kw)
         gen = lambda: gen_ycsb_batch(rng, cfg.batch_size, cfg.num_records,
                                      theta=cfg.theta, mix=cfg.mix)
     elif cfg.kind == "smallbank":
         wl = make_smallbank()
-        eng = BohmEngine(max(2 * cfg.num_records, 2), wl, mesh=mesh)
+        eng = BohmEngine(max(2 * cfg.num_records, 2), wl, mesh=mesh,
+                         **engine_kw)
         mixes = {"full": (0.2,) * 5, "balance": (1.0, 0, 0, 0, 0)}
         gen = lambda: gen_smallbank_batch(rng, cfg.batch_size,
                                           cfg.num_records,

@@ -47,7 +47,7 @@ import numpy as np
 
 from repro.core import plan as plan_mod
 from repro.core.execute import (Store, commit, execute_plan, init_store,
-                                store_from_base)
+                                place_store, store_from_base)
 from repro.core.plan import MAX_BATCH_TXNS, Plan, cc_plan
 from repro.core.txn import TxnBatch, Workload
 from repro.obs import MetricsRegistry, PhaseTracer, engine_health
@@ -71,7 +71,6 @@ class SnapshotHandle:
 class BohmEngine:
     def __init__(self, num_records: int, workload: Workload,
                  mesh=None, cc_axis: str = "cc", ring_slots: int = 4,
-                 resolve_interpret: Optional[bool] = None,
                  n_shards: Optional[int] = None,
                  spill_buckets: Optional[int] = None,
                  spill_slots: int = 8,
@@ -100,7 +99,7 @@ class BohmEngine:
         ``page_slots`` slots per shard (default: ``ceil(ring_slots /
         page_slots)`` pages per record, so every record can physically
         reach its initial capacity), per-record page tables, and
-        reads through the fused ``mvcc_resolve_paged`` kernel. Logical
+        reads through the page table into ``mvcc_resolve``. Logical
         semantics are the dense ring's; physically a cold record holds
         one page instead of ``k_max`` slots and capacity moves at page
         granularity (``reassign_k`` quantum = ``page_slots``, so
@@ -179,8 +178,6 @@ class BohmEngine:
         self.spill_buckets = int(spill_buckets if spill_buckets is not None
                                  else max(1, records_local // 4)
                                  ) if self.spill_slots > 0 else 0
-        # None = auto-select from jax.default_backend() inside the kernel
-        self.resolve_interpret = resolve_interpret
         self.store = init_store(num_records, workload.payload_words,
                                 ring_slots=self.k_max,
                                 n_shards=self.n_shards,
@@ -190,6 +187,7 @@ class BohmEngine:
                                 page_slots=self.page_slots or 4,
                                 pages_per_shard=self.pages_per_shard
                                 or None)
+        self.store = place_store(self.store, mesh, cc_axis)
         self._ts_next = 1                  # host mirror of store.ts_counter
         self._snapshots: Dict[int, SnapshotHandle] = {}
         self._next_sid = 0
@@ -221,8 +219,7 @@ class BohmEngine:
             gc_sharded_audited, event_cap=self.auditor.gc_event_cap))
         self._gather = jax.jit(gather_windows_sharded)
         self._readonly = jax.jit(functools.partial(
-            _readonly_resolve, mesh=mesh, cc_axis=cc_axis,
-            interpret=resolve_interpret))
+            _readonly_resolve, mesh=mesh, cc_axis=cc_axis))
 
     _SPILL_KEYS = ("spill_admitted", "spill_dropped",
                    "spill_overwrote_pinned")
@@ -312,6 +309,7 @@ class BohmEngine:
                                      page_slots=self.page_slots or 4,
                                      pages_per_shard=self.pages_per_shard
                                      or None)
+        self.store = place_store(self.store, self.mesh, self.cc_axis)
         self._ts_next = 1
         self._snapshots.clear()
         self._declare_metrics()
@@ -511,8 +509,7 @@ class BohmEngine:
         records = jnp.asarray(records, jnp.int32)
         ts_vec = jnp.full((records.shape[0],), int(ts), jnp.int32)
         return resolve_sharded(self.store.versions, records, ts_vec,
-                               mesh=self.mesh, axis=self.cc_axis,
-                               interpret=self.resolve_interpret)
+                               mesh=self.mesh, axis=self.cc_axis)
 
     def run_readonly_batch(self, batch: TxnBatch,
                            ts: Optional[int] = None
@@ -769,14 +766,14 @@ def _bohm_step(store: Store, batch: TxnBatch,
 
 
 def _readonly_resolve(versions, read_set: jax.Array, ts: jax.Array, *,
-                      mesh, cc_axis: str, interpret: Optional[bool]):
+                      mesh, cc_axis: str):
     """One fused device step for a read-only batch: per-shard gather of
     candidate windows, visibility through the Pallas kernel, pad mask."""
     T, Rd = read_set.shape
     flat = jnp.maximum(read_set.reshape(-1), 0)
     ts_vec = jnp.full((flat.shape[0],), ts, jnp.int32)
     vals, found = resolve_sharded(versions, flat, ts_vec, mesh=mesh,
-                                  axis=cc_axis, interpret=interpret)
+                                  axis=cc_axis)
     valid = read_set >= 0
     vals = jnp.where(valid[..., None], vals.reshape(T, Rd, -1), 0)
     found = jnp.where(valid, found.reshape(T, Rd), True)
@@ -794,7 +791,6 @@ def _readonly_resolve(versions, read_set: jax.Array, ts: jax.Array, *,
 def serial_oracle(store_base: jax.Array, batch: TxnBatch,
                   workload: Workload) -> Tuple[jax.Array, jax.Array]:
     """Returns (final_base [R, D], read_vals [T, Rd, D])."""
-    D = store_base.shape[1]
     R = store_base.shape[0]
 
     def step(base, txn):
@@ -803,10 +799,9 @@ def serial_oracle(store_base: jax.Array, batch: TxnBatch,
         vals = jnp.where((read_set >= 0)[..., None], vals, 0)
         write_vals, _ = jax.lax.switch(txn_type, list(workload.branches),
                                        vals, args)
+        # unused write slots index one past the end and are dropped
         rec = jnp.where(write_set >= 0, write_set, R)
-        base = jnp.concatenate([base, jnp.zeros((1, D), base.dtype)])
-        base = base.at[rec].set(write_vals, mode="drop")[:-1]
-        return base, vals
+        return base.at[rec].set(write_vals, mode="drop"), vals
 
     final, reads = jax.lax.scan(
         step, store_base,

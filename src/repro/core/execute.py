@@ -21,6 +21,8 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core.plan import Plan
 from repro.core.txn import TxnBatch, Workload
@@ -88,6 +90,22 @@ def store_from_base(base: jax.Array, base_ts: Optional[jax.Array] = None,
                                              k_init=k_init, paged=paged,
                                              page_slots=page_slots,
                                              pages_per_shard=pages_per_shard))
+
+
+def place_store(store: Store, mesh, axis: str = "cc") -> Store:
+    """Lay ``store`` out on ``mesh``: every [n, R/n, ...] leaf of the
+    version store splits over ``axis`` (each device holds its own
+    shards' rings / pages / spill pool), the head cache and the ts
+    counter replicate. A store whose shard count is not the axis size
+    (or no mesh) stays where it is."""
+    if mesh is None or mesh.shape.get(axis) != store.versions.n_shards:
+        return store
+    split = NamedSharding(mesh, P(axis))
+    whole = NamedSharding(mesh, P())
+    return Store(base=jax.device_put(store.base, whole),
+                 base_ts=jax.device_put(store.base_ts, whole),
+                 ts_counter=jax.device_put(store.ts_counter, whole),
+                 versions=jax.device_put(store.versions, split))
 
 
 def execute_plan(plan: Plan, batch: TxnBatch, store: Store,

@@ -32,7 +32,6 @@ import numpy as np
 
 from repro.core.txn import TxnBatch
 from repro.store.ring import INF_TS  # single home of the ts sentinel
-from repro.store.sharded import shard_map_compat as _shard_map
 
 # composite (record, ts) uint32 keys need R * T < 2^32 (R <= 2^20 records,
 # checked in the engine) — the one home of the batch/epoch size limit
@@ -146,16 +145,12 @@ def cc_plan_sharded(batch: TxnBatch, ts_base: jax.Array, mesh,
         return jax.tree.map(lambda x: x[None], p)   # add shard axis
 
     from jax.sharding import PartitionSpec as P
-    fn = _shard_map(
-        shard_fn, mesh=mesh,
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh, check_vma=False,
         in_specs=(P(), P(), P(), P(), P()),
         out_specs=jax.tree.map(lambda _: P(axis), _plan_structure()))
     return fn(batch.read_set, batch.write_set, batch.txn_type, batch.args,
               jnp.asarray(ts_base, jnp.int32))
-
-
-# (the jax-version shard_map compat shim lives in repro.store.sharded —
-# the storage layer is the single home; imported as _shard_map above)
 
 
 def _plan_structure():

@@ -1,9 +1,11 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On TPU the kernels lower natively; on this CPU-only substrate they run in
-``interpret=True`` mode (the kernel body executes in Python on CPU), which
-is what the per-kernel allclose tests in tests/test_kernels.py validate
-against the jnp oracles in ref.py.
+On TPU the kernels lower natively; on any other backend they run in
+``interpret=True`` mode (the kernel body executes in Python on the CPU),
+which is what the per-kernel tests in tests/test_kernels.py check
+against the jnp oracles in ref.py. The backend alone picks the mode
+(``default_interpret``); tests/test_tpu_compile.py compiles the native
+lowering for a described TPU v5e.
 """
 from __future__ import annotations
 
@@ -15,13 +17,10 @@ from repro.kernels.mvcc_resolve import default_interpret as _interpret
 from repro.kernels.mvcc_resolve import mvcc_resolve as _resolve
 from repro.kernels.mvcc_resolve import \
     mvcc_resolve_masked as _resolve_masked
-from repro.kernels.mvcc_resolve import \
-    mvcc_resolve_paged as _resolve_paged
 
 
 def mvcc_resolve(begin, end, data, ts, **kw):
-    # interpret auto-selection (backend-driven, explicitly overridable)
-    # lives in the kernel itself — pass through untouched
+    # interpret mode is picked from the backend inside the kernel module
     return _resolve(begin, end, data, ts, **kw)
 
 
@@ -29,12 +28,6 @@ def mvcc_resolve_masked(begin, end, rec, want, data, ts, **kw):
     # the spill-pool fall-through: shared bucket windows filtered by
     # owner record id inside the visibility test
     return _resolve_masked(begin, end, rec, want, data, ts, **kw)
-
-
-def mvcc_resolve_paged(page_rows, begin, end, data, ts, **kw):
-    # the paged-store primary: page-table gather fused into the
-    # visibility scan (block-table indirection over the slab)
-    return _resolve_paged(page_rows, begin, end, data, ts, **kw)
 
 
 def decode_attention(q, k, v, kv_len, **kw):
@@ -49,6 +42,5 @@ def flash_attention_causal(q, k, v, **kw):
 
 mvcc_resolve_ref = ref.mvcc_resolve_ref
 mvcc_resolve_masked_ref = ref.mvcc_resolve_masked_ref
-mvcc_resolve_paged_ref = ref.mvcc_resolve_paged_ref
 decode_attention_ref = ref.decode_attention_ref
 flash_attention_causal_ref = ref.flash_attention_causal_ref

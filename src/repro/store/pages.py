@@ -184,9 +184,8 @@ def mask_gathered_windows(pt: jax.Array, begin_g: jax.Array,
 def gather_windows_paged(slab: PageSlab, records: jax.Array
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Materialise per-read candidate windows through the page table:
-    records [B] -> (begin [B, MaxP*S], end, payload [B, MaxP*S, D]).
-    Diagnostic / host path — the hot read path is the fused
-    ``mvcc_resolve_paged`` kernel, which never materialises this."""
+    records [B] -> (begin [B, MaxP*S], end, payload [B, MaxP*S, D]) —
+    the paged store's input to the ``mvcc_resolve`` kernel."""
     rec = jnp.maximum(jnp.asarray(records, jnp.int32), 0)
     pt = slab.page_table[rec]                          # [B, MaxP]
     safe = jnp.maximum(pt, 0)

@@ -423,8 +423,8 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         out_struct = (_page_struct() if paged else _ring_struct(),
                       None if not with_spill else _spill_struct(),
                       _metrics_struct(with_spill, paged, with_audit))
-        prim, spill, per = _shard_map(
-            body, mesh=mesh,
+        prim, spill, per = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=jax.tree.map(lambda _: P(axis),
                                   (_primary(store), store.spill,
                                    store.k_eff)),
@@ -634,8 +634,7 @@ def gather_windows_sharded(store: ShardedVersionStore, records: jax.Array
     read, gathered from each record's owning shard (primary level only —
     the spill fall-through lives in ``resolve_sharded``). For a paged
     store the windows are materialised through the page table (K =
-    MaxP * S, unmapped pages contribute empty slots) — diagnostic path;
-    the hot read path keeps the gather fused in the kernel."""
+    MaxP * S, unmapped pages contribute empty slots)."""
     if store.n_shards == 1:
         prim = _ring0(store)
         if isinstance(prim, PageSlab):
@@ -657,37 +656,29 @@ def gather_windows_sharded(store: ShardedVersionStore, records: jax.Array
 
 
 def _resolve_two_level(prim_s, spill_s: Optional[SpillPool],
-                       local_rec: jax.Array, ts: jax.Array,
-                       interpret: Optional[bool]
+                       local_rec: jax.Array, ts: jax.Array
                        ) -> Tuple[jax.Array, jax.Array]:
     """Primary resolve with the spill fall-through: at most one of the
     two levels holds the version visible at ``ts`` (a version is evicted
     from the primary exactly when it moves to spill, and [begin, end)
     windows partition a record's timeline), so combining is a select.
-    The primary is either a dense ring (pre-gathered windows through
-    ``mvcc_resolve``) or a page slab (page-table rows through the fused
-    ``mvcc_resolve_paged`` — no window materialisation)."""
-    if isinstance(prim_s, PageSlab):
-        rows = prim_s.page_table[jnp.maximum(local_rec, 0)]
-        vals, found = ops.mvcc_resolve_paged(rows, prim_s.begin,
-                                             prim_s.end, prim_s.payload,
-                                             ts, interpret=interpret)
-    else:
-        begin, end, payload = gather_windows(prim_s, local_rec)
-        vals, found = ops.mvcc_resolve(begin, end, payload, ts,
-                                       interpret=interpret)
+    The primary's candidate windows come from the dense ring or, for a
+    page slab, through the page table (unmapped pages contribute empty
+    slots); both resolve through the same ``mvcc_resolve`` kernel."""
+    gather = gather_windows_paged if isinstance(prim_s, PageSlab) \
+        else gather_windows
+    vals, found = ops.mvcc_resolve(*gather(prim_s, local_rec), ts)
     if spill_s is None:
         return vals, found
     bkt = spill_buckets_for(local_rec, spill_s.begin.shape[0])
     s_vals, s_found = ops.mvcc_resolve_masked(
         spill_s.begin[bkt], spill_s.end[bkt], spill_s.rec[bkt],
-        local_rec, spill_s.payload[bkt], ts, interpret=interpret)
+        local_rec, spill_s.payload[bkt], ts)
     return jnp.where(found[:, None], vals, s_vals), found | s_found
 
 
 def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
-                    ts: jax.Array, mesh=None, axis: str = "cc",
-                    interpret: Optional[bool] = None
+                    ts: jax.Array, mesh=None, axis: str = "cc"
                     ) -> Tuple[jax.Array, jax.Array]:
     """Resolve ``records`` [B] at snapshot timestamps ``ts`` [B] through
     the Pallas kernel, PER SHARD: each shard runs ``mvcc_resolve`` over
@@ -700,13 +691,12 @@ def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
     if n == 1:
         local = jnp.maximum(records, 0)
         return _resolve_two_level(_ring0(store), _take_spill(store, 0),
-                                  local, ts, interpret)
+                                  local, ts)
 
     def one_shard(prim_s, spill_s, shard):
         owned = (records % n) == shard
         local = jnp.where(owned, records // n, 0)
-        vals, found = _resolve_two_level(prim_s, spill_s, local, ts,
-                                         interpret)
+        vals, found = _resolve_two_level(prim_s, spill_s, local, ts)
         return jnp.where(owned[:, None], vals, 0), owned & found
 
     if mesh is not None and axis in mesh.shape and mesh.shape[axis] == n:
@@ -722,8 +712,8 @@ def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
             return (jax.lax.psum(vals, axis),
                     jax.lax.psum(found.astype(jnp.int32), axis) > 0)
 
-        return _shard_map(
-            body, mesh=mesh,
+        return jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=jax.tree.map(lambda _: P(axis),
                                   (_primary(store), store.spill)),
             out_specs=(P(), P()))(_primary(store), store.spill)
@@ -739,18 +729,3 @@ def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
         vals = v_s if vals is None else vals + v_s
         found = f_s if found is None else found | f_s
     return vals, found
-
-
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map across jax versions (kwarg was renamed check_rep ->
-    check_vma when shard_map left jax.experimental). The single home of
-    this shim — the CC planner (repro.core.plan) imports it too."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
-_shard_map = shard_map_compat

@@ -3,7 +3,6 @@ bf16 round-trip, elastic reshard restore."""
 import tempfile
 from pathlib import Path
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -62,7 +61,8 @@ def test_elastic_reshard_restore():
     with tempfile.TemporaryDirectory() as d:
         m = CheckpointManager(d, async_save=False)
         m.save(1, _state(1.5))
-        mesh = jax.make_mesh((1,), ("data",))
+        from repro.runtime import make_mesh
+        mesh = make_mesh((1,), ("data",))
         sh = {"params": {"w": NamedSharding(mesh, P()),
                          "scale": NamedSharding(mesh, P())},
               "opt": {"m": {"w": NamedSharding(mesh, P())}}}

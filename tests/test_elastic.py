@@ -32,7 +32,8 @@ SCRIPT = textwrap.dedent("""
     step_fn = make_train_step(cfg, TrainConfig())
 
     # phase 1: big mesh (2 data x 2 model)
-    mesh1 = jax.make_mesh((2, 2), ("data", "model"))
+    from repro.runtime import make_mesh
+    mesh1 = make_mesh((2, 2), ("data", "model"))
     sh1 = shd.param_shardings(cfg, mesh1)
     with mesh1:
         params = jax.device_put(init_params(cfg, jax.random.PRNGKey(0)), sh1)
@@ -46,7 +47,7 @@ SCRIPT = textwrap.dedent("""
 
     # phase 2: half the devices "fail" -> remesh (1 data x 2 model)
     plan = plan_remesh(2, model_parallel=2, pods=1)
-    mesh2 = jax.make_mesh((plan.data, plan.model), ("data", "model"))
+    mesh2 = make_mesh((plan.data, plan.model), ("data", "model"))
     sh2 = {"params": shd.param_shardings(cfg, mesh2),
            "opt": {"m": shd.param_shardings(cfg, mesh2),
                    "v": shd.param_shardings(cfg, mesh2),

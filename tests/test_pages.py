@@ -38,6 +38,7 @@ from repro.core.workloads import gen_ycsb_batch, make_ycsb
 from repro.kernels import ops, ref
 from repro.service import TxnService
 from repro.store import decay_pressure, reassign_k
+from repro.store.pages import PageSlab, gather_windows_paged
 
 R, T = 64, 32
 
@@ -84,8 +85,20 @@ def _assert_engines_equal(dense, paged, snaps, psnaps):
 
 
 # ---------------------------------------------------------------------------
-# 1. the fused page-table resolve kernel == jnp reference
+# 1. the paged resolve path (page-table gather -> mvcc_resolve) == jnp
+#    reference
 # ---------------------------------------------------------------------------
+def _paged_resolve(pt, begin, end, data, ts):
+    """Read i resolves record i of a slab whose page table is ``pt`` —
+    the store's paged read path: ``gather_windows_paged`` then the
+    ``mvcc_resolve`` kernel."""
+    slab = PageSlab(begin=jnp.asarray(begin), end=jnp.asarray(end),
+                    payload=jnp.asarray(data), page_table=jnp.asarray(pt),
+                    head=jnp.zeros((pt.shape[0],), jnp.int32))
+    windows = gather_windows_paged(slab, jnp.arange(pt.shape[0]))
+    return ops.mvcc_resolve(*windows, jnp.asarray(ts))
+
+
 def test_paged_resolve_kernel_matches_ref():
     rng = np.random.default_rng(3)
     P, S, MaxP, B, D = 23, 3, 4, 37, 5
@@ -99,8 +112,7 @@ def test_paged_resolve_kernel_matches_ref():
         np.int32)
     pt[rng.random((B, MaxP)) < 0.4] = -1             # unmap some entries
     ts = rng.integers(0, 80, B).astype(np.int32)
-    v_k, f_k = ops.mvcc_resolve_paged(pt, begin, end, data, ts,
-                                      interpret=True)
+    v_k, f_k = _paged_resolve(pt, begin, end, data, ts)
     v_r, f_r = ref.mvcc_resolve_paged_ref(pt, jnp.asarray(begin),
                                           jnp.asarray(end),
                                           jnp.asarray(data),
@@ -112,10 +124,9 @@ def test_paged_resolve_kernel_matches_ref():
     # a fully-mapped single-page table degrades to the dense kernel over
     # that page's window
     pt1 = np.arange(B, dtype=np.int32)[:, None] % P
-    v_m, f_m = ops.mvcc_resolve_paged(pt1, begin, end, data, ts,
-                                      interpret=True)
+    v_m, f_m = _paged_resolve(pt1, begin, end, data, ts)
     v_p, f_p = ops.mvcc_resolve(begin[pt1[:, 0]], end[pt1[:, 0]],
-                                data[pt1[:, 0]], ts, interpret=True)
+                                data[pt1[:, 0]], ts)
     np.testing.assert_array_equal(np.asarray(v_m), np.asarray(v_p))
     np.testing.assert_array_equal(np.asarray(f_m), np.asarray(f_p))
 
@@ -396,7 +407,8 @@ _MESH_PAGED_SCRIPT = textwrap.dedent("""
     from repro.core.workloads import gen_ycsb_batch, make_ycsb
 
     R, T = 64, 32
-    mesh = jax.make_mesh((4,), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh(4)
     wl = make_ycsb(payload_words=2, ops=4)
     e_paged = BohmEngine(R, wl, mesh=mesh, ring_slots=2, paged=True,
                          page_slots=2, pages_per_shard=64,

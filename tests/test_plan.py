@@ -255,7 +255,8 @@ _SHARDED_PROPERTY_SCRIPT = textwrap.dedent("""
     from repro.core.txn import Workload, make_batch
 
     R, T, OPS = 32, 16, 3
-    mesh = jax.make_mesh((4,), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh(4)
 
     def rand_batch(seed):
         rng = np.random.default_rng(seed)
@@ -329,7 +330,8 @@ def test_sharded_plan_property_sweep():
                     reason="needs >1 device for the cc mesh axis")
 def test_sharded_plan_matches_unsharded():
     from repro.core.plan import cc_plan_sharded, merge_sharded_plan
-    mesh = jax.make_mesh((jax.device_count(),), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh()
     rng = np.random.default_rng(0)
     writes = rng.integers(0, 16, (8, 3))
     reads = rng.integers(0, 16, (8, 3))

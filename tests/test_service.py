@@ -335,7 +335,8 @@ _SHARDED_PIPELINE_SCRIPT = textwrap.dedent("""
     from repro.service import TxnService
 
     R, T, OPS = 32, 16, 3
-    mesh = jax.make_mesh((4,), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh(4)
 
     def rand_batch(seed):
         rng = np.random.default_rng(seed)
@@ -414,7 +415,8 @@ _CONFLICT_AWARE_SHARDED_SCRIPT = textwrap.dedent("""
     from repro.store import unshard
 
     R, T, OPS = 64, 16, 3
-    mesh = jax.make_mesh((4,), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh(4)
 
     def striped_batch(rng, stripe):
         lo = 16 * (stripe % 4)

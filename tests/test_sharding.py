@@ -9,11 +9,12 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import get_config, reduced_config
 from repro.models import abstract_params
 from repro.parallel import sharding as shd
+from repro.runtime import make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_spec_drops_nondivisible(mesh):

@@ -404,7 +404,8 @@ _MESH_SPILL_SCRIPT = textwrap.dedent("""
     from repro.core.workloads import gen_ycsb_batch, make_ycsb
 
     R, T = 64, 32
-    mesh = jax.make_mesh((4,), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh(4)
     wl = make_ycsb(payload_words=2, ops=4)
     e_mesh = BohmEngine(R, wl, mesh=mesh, ring_slots=2,
                         spill_buckets=16, spill_slots=16)

@@ -85,7 +85,7 @@ def test_sharded_commit_bit_identical(R, n_shards):
         # per-shard kernel resolution == single-ring kernel resolution
         recs = jnp.arange(R, dtype=jnp.int32)
         ts_vec = jnp.full((R,), ts_base - 1, jnp.int32)
-        v2, f2 = resolve_sharded(store, recs, ts_vec, interpret=True)
+        v2, f2 = resolve_sharded(store, recs, ts_vec)
         b0, e0, p0 = ring.begin[recs], ring.end[recs], ring.payload[recs]
         v1, f1 = ops.mvcc_resolve(b0, e0, p0, ts_vec, interpret=True)
         np.testing.assert_array_equal(np.asarray(v2), np.asarray(v1))
@@ -228,7 +228,8 @@ _MESH_SCRIPT = textwrap.dedent("""
     from repro.store import unshard
 
     R, T, OPS = 33, 16, 3
-    mesh = jax.make_mesh((4,), ("cc",))
+    from repro.runtime import cc_mesh
+    mesh = cc_mesh(4)
 
     def rand_batch(seed):
         rng = np.random.default_rng(seed)
@@ -249,6 +250,14 @@ _MESH_SCRIPT = textwrap.dedent("""
     e_mesh = BohmEngine(R, wl, mesh=mesh)
     e_one = BohmEngine(R, wl)
     assert e_mesh.n_shards == 4
+
+    def held(x):        # {device: leading shard-axis extent it holds}
+        return {str(s.device): s.data.shape[0]
+                for s in x.addressable_shards}
+
+    # placed at init: each device holds one shard's ring and spill pool
+    for leaf in jax.tree.leaves(e_mesh.store.versions):
+        assert held(leaf) == {str(d): 1 for d in mesh.devices.flat}
     snap_m = snap_o = None
     for i in range(3):
         batch = rand_batch(i)
@@ -271,6 +280,9 @@ _MESH_SCRIPT = textwrap.dedent("""
     np.testing.assert_array_equal(np.asarray(f_m), np.asarray(f_o))
     vals, found, m = e_mesh.run_readonly_batch(rand_batch(9))
     assert float(m["found_frac"]) == 1.0
+    # and the commits keep it there
+    assert held(e_mesh.store.versions.rings.begin) == \
+        {str(d): 1 for d in mesh.devices.flat}
     print("MESH_STORE_OK")
 """)
 

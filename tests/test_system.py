@@ -99,7 +99,8 @@ def test_paper_workload_configs():
 def test_sequence_parallel_constraint():
     from repro.parallel.constraints import activation_mesh, \
         constrain_residual
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.runtime import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jnp.ones((4, 8, 16))
     with activation_mesh(mesh, sequence_parallel=True):
         y = jax.jit(constrain_residual)(x)
