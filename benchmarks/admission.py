@@ -300,11 +300,10 @@ def bench_latency(rng, n_passes: int) -> list:
 
 
 def trace_stream(kind: str = "mixed") -> None:
-    """One traced pass over the stream (SEPARATE from the timed cells —
-    tracing fences every span close, which would distort the timing):
-    exports ``results/admission_trace.json``, a Chrome-trace view of the
-    scheduler's plan/exec/commit spans and its merge / hop / chain /
-    class-promotion decisions."""
+    """One traced pass over the stream (SEPARATE from the timed cells,
+    which keep the ring off): exports ``results/admission_trace.json``,
+    a Chrome-trace view of the scheduler's ``service/*`` host spans and
+    its merge / hop / chain / class-promotion decisions."""
     rng = np.random.default_rng(47)
     wl = make_ycsb(payload_words=2)
     eng = BohmEngine(N_RECORDS, wl, ring_slots=RING_SLOTS,
@@ -340,8 +339,8 @@ def trace_stream(kind: str = "mixed") -> None:
 
 def flight_stream(kind: str = "mixed") -> None:
     """One flight-recorded pass over the stream (separate from the timed
-    cells — the stitched export also enables the phase tracer, whose
-    span fences would distort timing): every ticket is waited
+    cells — the stitched export also enables the phase tracer's ring):
+    every ticket is waited
     individually so lifecycle records complete at retrieval, then
 
       * ``results/admission_flight_trace.json`` — the PhaseTracer spans

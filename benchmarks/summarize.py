@@ -158,11 +158,11 @@ def print_obs_section() -> bool:
               f"git {meta.get('git_sha')} / {meta.get('timestamp')}\n")
     phases = data.get("phases") or []
     if phases:
-        print("| phase | count | mean ms | p50 ms | max ms | anomalies |")
-        print("|---|---|---|---|---|---|")
+        print("| span | count | mean ms | p50 ms | max ms |")
+        print("|---|---|---|---|---|")
         for p in phases:
             print(f"| {p['phase']} | {p['count']} | {p['mean_ms']} | "
-                  f"{p['p50_ms']} | {p['max_ms']} | {p['anomalies']} |")
+                  f"{p['p50_ms']} | {p['max_ms']} |")
     health = data.get("health") or {}
     gauges = [k for k in ("watermark_lag", "active_pins", "live_versions",
                           "ring_fill_p50", "ring_fill_max",

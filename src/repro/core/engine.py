@@ -36,7 +36,6 @@ and never write shared state — the paper's zero-bookkeeping read path.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -52,6 +51,7 @@ from repro.core.plan import MAX_BATCH_TXNS, Plan, cc_plan
 from repro.core.txn import TxnBatch, Workload
 from repro.obs import MetricsRegistry, PhaseTracer, engine_health
 from repro.obs.lifecycle import NULL_AUDIT, LifecycleAuditor
+from repro.runtime import jit_named
 from repro.store import (INF_TS, decay_pressure, from_global,
                          gather_windows_sharded, gc_sharded,
                          gc_sharded_audited, reassign_k, reassign_stats,
@@ -121,12 +121,12 @@ class BohmEngine:
         transfer point. Default: a private registry, so the legacy stats
         surfaces (``overflow_stats`` / ``spill_stats`` /
         ``storage_stats``) work stand-alone. ``tracer`` (optional
-        ``repro.obs.PhaseTracer``) wraps plan/exec/commit, ``gc_sweep``
-        and ``reassign_k`` in wall-clock spans, fenced by
-        ``block_until_ready`` only at span close when tracing is enabled
-        — disabled tracing (the default) adds no host syncs. ``auditor``
-        (optional ``repro.obs.LifecycleAuditor``) turns on the version-
-        lifecycle audit: the commit jit emits fixed-shape ``audit_*``
+        ``repro.obs.PhaseTracer``) wraps plan/exec/commit, the read-only
+        resolve, ``gc_sweep`` and ``reassign_k`` in unfenced host spans
+        (``engine/*``); the default tracer records nothing and only
+        annotates the profiler's trace, and no tracer adds a host sync.
+        ``auditor`` (optional ``repro.obs.LifecycleAuditor``) turns on the
+        version-lifecycle audit: the commit jit emits fixed-shape ``audit_*``
         transition arrays, ``gc_sweep`` runs the audited sweep (delay
         distribution + pin certification) and harvests the bounded host
         audit ring — still zero fences on or off (the audit arrays ride
@@ -194,7 +194,7 @@ class BohmEngine:
         self.metrics = registry if registry is not None \
             else MetricsRegistry()
         self.tracer = tracer if tracer is not None \
-            else PhaseTracer(enabled=False)
+            else PhaseTracer(enabled=False, annotate=True)
         self.auditor = auditor if auditor is not None else NULL_AUDIT
         self._declare_metrics()
         # adaptive-K hysteresis: a record donates capacity only after
@@ -205,21 +205,20 @@ class BohmEngine:
         # the cumulative histogram at the last sweep (for deltas)
         self._pressure_ewma = np.zeros((num_records,), np.float64)
         self._overflow_at_sweep = np.zeros((num_records,), np.int64)
-        self._step = jax.jit(functools.partial(
-            _bohm_step, workload=workload, mesh=mesh, cc_axis=cc_axis))
-        self._plan = jax.jit(functools.partial(
-            plan_phase, mesh=mesh, cc_axis=cc_axis))
-        self._exec = jax.jit(functools.partial(
-            exec_phase, workload=workload))
-        self._commit = jax.jit(functools.partial(
-            commit_phase, mesh=mesh, cc_axis=cc_axis,
-            with_audit=self.auditor.enabled))
+        # each phase's program carries its function's name on the device
+        # (``jit_commit_phase``, ...), which the profiler's trace shows
+        self._step = jit_named(_bohm_step, workload=workload, mesh=mesh,
+                               cc_axis=cc_axis)
+        self._plan = jit_named(plan_phase, mesh=mesh, cc_axis=cc_axis)
+        self._exec = jit_named(exec_phase, workload=workload)
+        self._commit = jit_named(commit_phase, mesh=mesh, cc_axis=cc_axis,
+                                 with_audit=self.auditor.enabled)
         self._gc = jax.jit(gc_sharded)
-        self._gc_audit = jax.jit(functools.partial(
-            gc_sharded_audited, event_cap=self.auditor.gc_event_cap))
+        self._gc_audit = jit_named(gc_sharded_audited,
+                                   event_cap=self.auditor.gc_event_cap)
         self._gather = jax.jit(gather_windows_sharded)
-        self._readonly = jax.jit(functools.partial(
-            _readonly_resolve, mesh=mesh, cc_axis=cc_axis))
+        self._readonly = jit_named(_readonly_resolve, mesh=mesh,
+                                   cc_axis=cc_axis)
 
     _SPILL_KEYS = ("spill_admitted", "spill_dropped",
                    "spill_overwrote_pinned")
@@ -256,16 +255,14 @@ class BohmEngine:
         tr = self.tracer
         wm = jnp.asarray(self.watermark(), jnp.int32)
         pins = self.pin_array()
-        with tr.span("plan_phase", txns=batch.size) as sp:
-            plan = sp.fence(self._plan(batch, self.store.ts_counter))
-        with tr.span("exec_phase", txns=batch.size) as sp:
+        with tr.span("engine/plan", txns=batch.size):
+            plan = self._plan(batch, self.store.ts_counter)
+        with tr.span("engine/exec", txns=batch.size):
             w_data, read_vals, exec_metrics = self._exec(plan, batch,
                                                          self.store)
-            sp.fence(read_vals)
-        with tr.span("commit_phase", txns=batch.size) as sp:
+        with tr.span("engine/commit", txns=batch.size):
             self.store, ring_metrics = self._commit(
                 plan, batch, self.store, w_data, wm, None, pins)
-            sp.fence(self.store.base)
         metrics = dict(exec_metrics, **ring_metrics)
         self.claim_ts_window(batch.size)
         self.record_commit_metrics(metrics, n_txns=batch.size)
@@ -384,7 +381,7 @@ class BohmEngine:
         Returns the number of versions reclaimed (rings + spill);
         synchronises on it."""
         wm_host = self.watermark()
-        with self.tracer.span("gc_sweep", watermark=wm_host) as sp:
+        with self.tracer.span("engine/gc_sweep", watermark=wm_host) as sp:
             wm = jnp.asarray(wm_host, jnp.int32)
             if self.auditor.enabled:
                 versions, evicted, gc_audit = self._gc_audit(
@@ -415,7 +412,7 @@ class BohmEngine:
         """One adaptive-K ``reassign_k`` pass at the sweep boundary
         (host-side; its own trace span — the policy is the sweep's
         expensive part and worth separate attribution)."""
-        with self.tracer.span("reassign_k") as sp:
+        with self.tracer.span("engine/reassign_k") as sp:
             cumulative = np.asarray(
                 to_global(versions,
                           self.metrics.peek("engine/ring_overwrote_rec")),
@@ -525,12 +522,11 @@ class BohmEngine:
             ts = ts.ts
         if ts is None:
             ts = self.current_ts()
-        with self.tracer.span("read/resolve", txns=batch.size,
-                              ts=int(ts)) as sp:
+        with self.tracer.span("engine/readonly", txns=batch.size,
+                              ts=int(ts)):
             vals, found, metrics = self._readonly(
                 self.store.versions, batch.read_set,
                 jnp.asarray(int(ts), jnp.int32))
-            sp.fence(vals)
         return vals, found, metrics
 
     # -- K-ring pressure diagnostics ---------------------------------------

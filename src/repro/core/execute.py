@@ -202,15 +202,20 @@ def commit(plan: Plan, batch: TxnBatch, store: Store, w_data: jax.Array,
         ts_window = (plan.ts_base,
                      plan.ts_base + batch.read_set.shape[0])
     R = store.base.shape[0]
-    rec = jnp.where(plan.commit_mask, plan.w_rec, R)          # drop pads
-    base = jnp.concatenate([store.base,
-                            jnp.zeros((1,) + store.base.shape[1:],
-                                      store.base.dtype)])
-    base = base.at[rec].set(w_data, mode="drop")[:-1]
-    ts = plan.ts_base + plan.w_txn
-    base_ts = jnp.concatenate([store.base_ts, jnp.zeros((1,), jnp.int32)])
-    base_ts = base_ts.at[rec].set(jnp.where(plan.commit_mask, ts, 0),
-                                  mode="drop")[:-1]
+    # stage scopes (``commit/head`` here, ``commit/ring`` and
+    # ``commit/spill`` in the store) name the ops in the program's
+    # metadata only; the device trace reads each stage's time from them
+    with jax.named_scope("commit/head"):
+        rec = jnp.where(plan.commit_mask, plan.w_rec, R)      # drop pads
+        base = jnp.concatenate([store.base,
+                                jnp.zeros((1,) + store.base.shape[1:],
+                                          store.base.dtype)])
+        base = base.at[rec].set(w_data, mode="drop")[:-1]
+        ts = plan.ts_base + plan.w_txn
+        base_ts = jnp.concatenate([store.base_ts,
+                                   jnp.zeros((1,), jnp.int32)])
+        base_ts = base_ts.at[rec].set(jnp.where(plan.commit_mask, ts, 0),
+                                      mode="drop")[:-1]
     versions, ring_metrics = commit_sharded(
         store.versions, plan.w_rec, plan.w_key, plan.w_valid,
         plan.w_begin_ts, plan.w_end_ts, w_data, watermark,

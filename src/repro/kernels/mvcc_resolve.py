@@ -7,7 +7,9 @@ per-record version window held in VMEM, fused with the payload select so
 the kernel reads each version window once. The wrapper's pad and
 transpose to the lane-major layout below run in XLA before the kernel;
 unless XLA fuses them into the caller's gather they add one more pass
-over the windows (not measured yet).
+over the windows. They carry the ``resolve/layout`` scope in the
+program's op metadata and the kernel ``resolve/kernel`` (the callers'
+window gathers ``resolve/gather``), so a device trace times each.
 
 Callers pre-gather the candidate windows per read (XLA's gather is the
 efficient primitive for the HBM-resident [R, K] rings, and the paged
@@ -118,23 +120,31 @@ def _resolve_call(kernel, per_read, windows, data, *, block_b, block_d,
     d = data.shape[-1]
     bb, bp, dd, dp = _tiles(b, d, block_b, block_d)
     pad_b = bp - b
-    rows = [jnp.pad(x, (0, pad_b))[None, :] for x in per_read]   # [1, Bp]
-    wins = [jnp.pad(w, ((0, pad_b), (0, 0))).T for w in windows]  # [K, Bp]
-    data_t = jnp.pad(data, ((0, pad_b), (0, 0), (0, dp - d))
-                     ).transpose(1, 2, 0)                         # [K,Dp,Bp]
-    vals, found = pl.pallas_call(
-        kernel,
-        grid=(bp // bb, dp // dd),
-        in_specs=[pl.BlockSpec((1, bb), lambda i, j: (0, i))] * len(rows)
-        + [pl.BlockSpec((k, bb), lambda i, j: (0, i))] * len(wins)
-        + [pl.BlockSpec((k, dd, bb), lambda i, j: (0, j, i))],
-        out_specs=[pl.BlockSpec((dd, bb), lambda i, j: (j, i)),
-                   pl.BlockSpec((1, bb), lambda i, j: (0, i))],
-        out_shape=[jax.ShapeDtypeStruct((dp, bp), data.dtype),
-                   jax.ShapeDtypeStruct((1, bp), jnp.int32)],
-        interpret=interpret,
-    )(*rows, *wins, data_t)
-    return vals[:d, :b].T, found[0, :b] != 0
+    # the relayout on both sides of the kernel is ``resolve/layout`` in
+    # the program's op metadata, the kernel ``resolve/kernel``
+    with jax.named_scope("resolve/layout"):
+        rows = [jnp.pad(x, (0, pad_b))[None, :]
+                for x in per_read]                                # [1, Bp]
+        wins = [jnp.pad(w, ((0, pad_b), (0, 0))).T
+                for w in windows]                                 # [K, Bp]
+        data_t = jnp.pad(data, ((0, pad_b), (0, 0), (0, dp - d))
+                         ).transpose(1, 2, 0)                     # [K,Dp,Bp]
+    with jax.named_scope("resolve/kernel"):
+        vals, found = pl.pallas_call(
+            kernel,
+            grid=(bp // bb, dp // dd),
+            in_specs=[pl.BlockSpec((1, bb), lambda i, j: (0, i))]
+            * len(rows)
+            + [pl.BlockSpec((k, bb), lambda i, j: (0, i))] * len(wins)
+            + [pl.BlockSpec((k, dd, bb), lambda i, j: (0, j, i))],
+            out_specs=[pl.BlockSpec((dd, bb), lambda i, j: (j, i)),
+                       pl.BlockSpec((1, bb), lambda i, j: (0, i))],
+            out_shape=[jax.ShapeDtypeStruct((dp, bp), data.dtype),
+                       jax.ShapeDtypeStruct((1, bp), jnp.int32)],
+            interpret=interpret,
+        )(*rows, *wins, data_t)
+    with jax.named_scope("resolve/layout"):
+        return vals[:d, :b].T, found[0, :b] != 0
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "block_d",

@@ -10,12 +10,18 @@ perturbs exactly what it measures. The layers:
                sync, no per-batch Python arithmetic on device values) and
                ONE host transfer at ``snapshot()``. The engine's and
                schedulers' legacy stats surfaces are views onto it.
-``trace``      ``PhaseTracer``: bounded-ring span instrumentation around
-               plan/exec/commit, gc_sweep, reassign_k and admission
-               decisions, fenced by ``block_until_ready`` only at span
-               close when tracing is ON (OFF = zero overhead, tested).
-               Exports Chrome ``trace_event`` JSON (Perfetto-loadable);
-               optional ``jax.profiler.TraceAnnotation`` passthrough.
+``trace``      ``PhaseTracer``: host spans around the engine's phase
+               dispatches (``engine/*``) and the scheduler's admission,
+               epoch formation, dispatches and joins (``service/*``).
+               Spans are unfenced host intervals — tracing never waits
+               on the device, on or off (tested). With ``annotate`` they
+               are ``jax.profiler.TraceAnnotation``s, on the profiler's
+               clock beside the device's programs, which are named after
+               their functions (``jit_commit_phase``) and carry
+               ``jax.named_scope`` stages (``commit/spill``) in their
+               ops' metadata: device time is read there. ``enabled``
+               adds a bounded ring exported as Chrome ``trace_event``
+               JSON (Perfetto-loadable).
 ``flight``     ``FlightRecorder``: per-ticket lifecycle records through
                the out-of-order scheduler (submit → dispatch → exec →
                commit → visible), telescoping latency breakdowns,
@@ -39,10 +45,6 @@ perturbs exactly what it measures. The layers:
                into bounded ring-buffer series, EWMA anomaly alerts
                (warn/crit JSONL event log), Chrome counter-track export
                stitched into the phase/flight trace.
-``regress``    benchmark trajectory: append-only ``BENCH_<suite>.json``
-               histories at the repo root (``run_metadata()``-stamped)
-               gated by ``EwmaAnomaly`` baselines (see
-               ``benchmarks/bench_history.py``).
 
 ``ewma`` (shared anomaly baselines) and ``meta`` (``run_metadata()``
 provenance stamping for benchmark artifacts) ride along.
@@ -57,8 +59,6 @@ from repro.obs.lifecycle import (NULL_AUDIT, AuditEvent, LifecycleAuditor,
 from repro.obs.meta import git_sha, run_metadata
 from repro.obs.monitor import NULL_MONITOR, HealthMonitor
 from repro.obs.quantiles import LogHistogram
-from repro.obs.regress import (Regression, append_entry, check_history,
-                               direction_for, history_path, load_history)
 from repro.obs.registry import MetricsRegistry, MetricsView
 from repro.obs.trace import (NULL_SPAN, PhaseTracer, validate_chrome_trace)
 
@@ -67,8 +67,7 @@ __all__ = [
     "HealthMonitor", "LifecycleAuditor", "LogHistogram",
     "MetricsRegistry", "MetricsView", "NULL_AUDIT", "NULL_FLIGHT",
     "NULL_MONITOR", "NULL_SPAN", "PhaseTracer", "RecordTimeline",
-    "Regression", "TicketFlight", "append_entry", "check_history",
-    "direction_for", "engine_health", "git_sha", "history_path",
-    "load_history", "run_metadata", "scheduler_health", "service_health",
-    "stitch_chrome_trace", "validate_chrome_trace",
+    "TicketFlight", "engine_health", "git_sha", "run_metadata",
+    "scheduler_health", "service_health", "stitch_chrome_trace",
+    "validate_chrome_trace",
 ]

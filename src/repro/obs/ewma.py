@@ -2,8 +2,8 @@
 
 One home for the EWMA arithmetic that was previously inlined in
 ``repro.ft.monitor.StragglerDetector`` (step-time straggler flagging) and
-is now shared with the observability layer (phase-span duration
-anomalies in ``repro.obs.trace``). Two pieces:
+is now shared with the observability layer (the health monitor's
+alerts in ``repro.obs.monitor``). Two pieces:
 
 ``Ewma``          the bare estimator: ``v <- (1-alpha) * v + alpha * x``,
                   seeded by the first sample (no bias-correction warmup —

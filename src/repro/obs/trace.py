@@ -1,41 +1,41 @@
-"""Phase tracing: bounded span ring + Chrome ``trace_event`` export.
+"""Phase tracing: host spans on the profiler's clock, plus an optional
+bounded span ring with Chrome ``trace_event`` export.
 
-Span instrumentation around the engine's phase graph (plan / exec /
-commit), the scheduler's admission decisions (merge / overlap /
-fallback), ``gc_sweep`` and ``reassign_k`` — recorded into a bounded
-in-memory event ring with wall-clock timing.
+Spans wrap the engine's phase dispatches (``engine/*``), the scheduler's
+admission, epoch formation, dispatches and joins (``service/*``),
+``engine/gc_sweep`` and ``engine/reassign_k``. A span records the HOST
+interval of the work it wraps and nothing else: JAX dispatch is
+asynchronous, so a dispatch span is the host's cost of enqueueing the
+program, and a join span (``service/wait``, ``service/backpressure``)
+is the time the host spent blocked on the device. No span fences:
+tracing never waits on a device value, so it never serialises the
+dispatch-ahead pipeline it measures. The device's time is read from the
+device trace instead, where every phase is a program named after its
+function (``jit_commit_phase``, ...) and the stages inside it carry
+``jax.named_scope`` names (``commit/head``, ``resolve/layout``, ...) in
+their ops' metadata.
 
-JAX dispatch is asynchronous, so a span that only timed the Python call
-would measure queue-push latency, not the phase. A span therefore takes a
-**fence**: the device output whose realisation marks the phase's end.
-``sp.fence(x)`` registers it; span close calls ``jax.block_until_ready``
-on the fence and stamps the end time after it. That sync is the entire
-cost of tracing — and it happens ONLY when tracing is enabled:
+Two independent switches:
 
-  * ``tracer.span(...)`` with ``enabled=False`` returns a shared no-op
-    span whose enter/exit/fence do nothing — no timestamps, no event
-    allocation, and crucially **no block_until_ready** (the
-    zero-overhead-when-off property the tests assert with a
-    transfer-count guard);
-  * ``instant(...)`` with ``enabled=False`` is a single attribute test.
+  * ``annotate=True`` enters each span as a ``jax.profiler.
+    TraceAnnotation`` carrying the span's arguments (an epoch's spans
+    share ``epoch=<dispatch_log index>``). With no profiler session
+    open that costs about a microsecond a span; inside
+    ``jax.profiler.start_trace`` / ``stop_trace`` the spans land in the
+    host planes of the trace, on the same clock as the device's
+    programs and ops. The engine's default tracer is
+    ``PhaseTracer(enabled=False, annotate=True)``.
+  * ``enabled=True`` also records every span and ``instant`` into a
+    ``deque(maxlen=capacity)`` ring — a long-running service keeps the
+    most recent window and counts what it dropped — exported as Chrome
+    ``trace_event`` JSON (the ``{"traceEvents": [...]}`` object format):
+    well-formed B/E pairs per (pid, tid) plus thread-scoped instants,
+    loadable in Perfetto / ``chrome://tracing``.
+    ``validate_chrome_trace`` checks the invariants CI enforces on
+    exported artifacts (B/E LIFO matching, monotonic timestamps).
 
-Events live in a ``deque(maxlen=capacity)`` ring — a long-running service
-keeps the most recent window and counts what it dropped. Export is Chrome
-``trace_event`` JSON (the ``{"traceEvents": [...]}`` object format):
-well-formed B/E pairs per (pid, tid) plus thread-scoped instants, loadable
-in Perfetto / ``chrome://tracing``. ``validate_chrome_trace`` checks the
-invariants CI enforces on exported artifacts (B/E LIFO matching,
-monotonic timestamps).
-
-``annotate=True`` additionally wraps each span in
-``jax.profiler.TraceAnnotation`` so spans show up inside a device
-profiler capture when one is active (passthrough only — absent in old
-jax versions, silently skipped).
-
-Per-name EWMA anomaly baselines (``repro.obs.ewma.EwmaAnomaly``) flag
-spans whose duration exceeds ``anomaly_threshold`` x their own baseline;
-flagged spans carry ``"anomaly": true`` in their E-event args and are
-counted in ``tracer.anomalies``.
+With both off, ``span`` returns the shared no-op ``NULL_SPAN`` and
+``instant`` is a single attribute test.
 """
 from __future__ import annotations
 
@@ -47,13 +47,11 @@ from typing import Dict, List, Optional
 
 import jax
 
-from repro.obs.ewma import EwmaAnomaly
-
 _US = 1e6
 
 
 class _NullSpan:
-    """Shared no-op span — the entire disabled-tracing hot path."""
+    """Shared no-op span — the hot path with tracing and annotation off."""
     __slots__ = ()
 
     def __enter__(self):
@@ -62,9 +60,6 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
-    def fence(self, x):
-        return x
-
     def note(self, **kw):
         pass
 
@@ -72,23 +67,34 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _AnnotatedSpan:
+    """A span that only annotates the profiler's trace (``enabled``
+    off): nothing is recorded in the ring."""
+    __slots__ = ("_ann",)
+
+    def __init__(self, ann):
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        return self._ann.__exit__(*exc)
+
+    def note(self, **kw):
+        pass
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "args", "_t0", "_fence", "_ann",
-                 "_notes")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_ann", "_notes")
 
     def __init__(self, tracer: "PhaseTracer", name: str, args: Dict):
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._fence = None
         self._ann = None
         self._notes: Optional[Dict] = None
-
-    def fence(self, x):
-        """Register the device value whose realisation ends this span
-        (returned unchanged, so call sites stay expression-shaped)."""
-        self._fence = x
-        return x
 
     def note(self, **kw):
         """Attach result attributes discovered inside the span (policy
@@ -100,7 +106,7 @@ class _Span:
     def __enter__(self):
         tr = self._tracer
         if tr.annotate and tr._annotation is not None:
-            self._ann = tr._annotation(self.name)
+            self._ann = tr._annotation(self.name, **self.args)
             self._ann.__enter__()
         self._t0 = tr._clock()
         tr._push("B", self.name, self._t0, self.args)
@@ -108,26 +114,19 @@ class _Span:
 
     def __exit__(self, *exc):
         tr = self._tracer
-        if self._fence is not None:
-            jax.block_until_ready(self._fence)
         if self._ann is not None:
             self._ann.__exit__(*exc)
         t1 = tr._clock()
-        dt = t1 - self._t0
-        args: Dict = {"dur_ms": round(dt * 1e3, 4)}
+        args: Dict = {"dur_ms": round((t1 - self._t0) * 1e3, 4)}
         if self._notes:
             args.update(self._notes)
-        if tr._flag_anomaly(self.name, dt):
-            args["anomaly"] = True
         tr._push("E", self.name, t1, args)
         return False
 
 
 class PhaseTracer:
     def __init__(self, capacity: int = 8192, enabled: bool = False,
-                 annotate: bool = False,
-                 anomaly_alpha: float = 0.1,
-                 anomaly_threshold: Optional[float] = None):
+                 annotate: bool = False):
         if capacity < 2:
             raise ValueError("capacity must hold at least one B/E pair")
         self.enabled = enabled
@@ -138,19 +137,17 @@ class PhaseTracer:
         self._t0: Optional[float] = None
         self.dropped = 0
         self._annotation = getattr(jax.profiler, "TraceAnnotation", None)
-        self._anomaly_alpha = anomaly_alpha
-        self._anomaly_threshold = anomaly_threshold
-        self._baselines: Dict[str, EwmaAnomaly] = {}
-        self.anomalies: Dict[str, int] = {}
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, **args):
-        """Context manager for one phase span. Disabled tracing returns
-        the shared no-op span (no allocation beyond the kwargs dict the
-        caller already built, no fence sync at exit)."""
-        if not self.enabled:
-            return NULL_SPAN
-        return _Span(self, name, args)
+        """Context manager for one host span; ``args`` go to the
+        annotation and to the ring's B event. With both switches off it
+        returns the shared no-op span."""
+        if self.enabled:
+            return _Span(self, name, args)
+        if self.annotate and self._annotation is not None:
+            return _AnnotatedSpan(self._annotation(name, **args))
+        return NULL_SPAN
 
     def instant(self, name: str, **args) -> None:
         """Thread-scoped instant event (admission decisions etc.)."""
@@ -164,18 +161,6 @@ class PhaseTracer:
         if len(self._events) == self.capacity:
             self.dropped += 1
         self._events.append((ph, name, t, args))
-
-    def _flag_anomaly(self, name: str, dt: float) -> bool:
-        if self._anomaly_threshold is None:
-            return False
-        det = self._baselines.get(name)
-        if det is None:
-            det = self._baselines[name] = EwmaAnomaly(
-                self._anomaly_alpha, self._anomaly_threshold)
-        if det.record(dt):
-            self.anomalies[name] = self.anomalies.get(name, 0) + 1
-            return True
-        return False
 
     def clear(self) -> None:
         self._events.clear()

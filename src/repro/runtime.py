@@ -1,15 +1,17 @@
 """Process-level JAX setup shared by the engine, the benchmarks, the tests
-and ``chip_smoke.py``: mesh construction, the persistent compile cache,
-and forced host devices for CPU rehearsals of multi-device paths.
+and ``chip_smoke.py``: mesh construction, named jits, the persistent
+compile cache, and forced host devices for CPU rehearsals of
+multi-device paths.
 
 Nothing here runs at import time, and importing this module initialises
 no JAX backend.
 """
 from __future__ import annotations
 
+import functools
 import os
 from pathlib import Path
-from typing import Optional, Sequence
+from typing import Callable, Optional, Sequence
 
 import jax
 from jax.sharding import AxisType, Mesh
@@ -37,6 +39,20 @@ def cc_mesh(n: Optional[int] = None,
     if n is not None:
         devices = devices[:n]
     return make_mesh((len(devices),), ("cc",), devices=devices)
+
+
+def jit_named(fn: Callable, **bound) -> Callable:
+    """``jax.jit`` of ``fn`` with the keyword arguments ``bound`` fixed,
+    its program named ``jit_<fn name>`` on the device and in the
+    profiler's trace (a bare ``functools.partial`` is named
+    ``jit__unknown``). Only the name is copied: a ``__wrapped__``
+    (``functools.wraps``) would make JAX bind the arguments against
+    ``fn``'s own signature, fail on the bound keywords, and rename the
+    program's parameters."""
+    phase = functools.partial(fn, **bound)
+    phase.__name__ = fn.__name__
+    phase.__qualname__ = fn.__qualname__
+    return jax.jit(phase)
 
 
 def setup_compile_cache() -> str:

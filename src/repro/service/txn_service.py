@@ -55,6 +55,12 @@ is provably serial-equivalent — byte-identical reads per ticket.
                     batches, so the pinned snapshot reads exactly what
                     the submission-order schedule would expose.
 
+Host spans (through the engine's ``PhaseTracer``, unfenced):
+``service/admit`` (the footprint), ``service/form_epoch``,
+``service/plan`` / ``service/exec`` / ``service/commit`` (the jitted
+dispatches) and the joins ``service/backpressure`` and ``service/wait``;
+the spans of one epoch carry ``epoch=<dispatch_log index>``.
+
 Correctness model: a hop swaps only commuting batches, so per-ticket read
 values and the head store equal the submission-order sequential schedule;
 version begin/end timestamps in the rings follow the dispatch order, so
@@ -71,7 +77,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import Deque, Dict, Iterable, List, Optional, Union
+from typing import Deque, Dict, Iterable, List, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -129,6 +135,7 @@ class _Planned:
     ts_base: int
     watermark: int
     pin_ts: jax.Array               # registered pins at plan time
+    epoch: int                      # index in ``dispatch_log``
 
     @property
     def size(self) -> int:
@@ -159,10 +166,10 @@ class TxnService:
         self._next_ticket = 0
         self._admission: Deque[_Admitted] = deque()
         self._planned: Deque[_Planned] = deque()
-        # unrealised exec steps: ONE entry (the epoch's ticket list) per
-        # dispatched epoch — a merged epoch is a single exec step, so the
-        # max_inflight bound counts epochs, not batches
-        self._inflight: Deque[List[int]] = deque()
+        # unrealised exec steps: ONE entry (epoch index, the epoch's
+        # ticket list) per dispatched epoch — a merged epoch is a single
+        # exec step, so the max_inflight bound counts epochs, not batches
+        self._inflight: Deque[Tuple[int, List[int]]] = deque()
         self._results: Dict[int, BatchResult] = {}
         # epochs in dispatch (= timestamp) order, each a ticket list in
         # concatenation order: sequential run_batch calls in this order
@@ -236,8 +243,9 @@ class TxnService:
             raise ValueError(f"unknown latency_class {latency_class!r}")
         ticket = self._next_ticket
         self._next_ticket += 1
-        fp = batch_footprint(batch, self.engine.num_records) \
-            if self.conflict_aware else None
+        with self.tracer.span("service/admit", ticket=ticket):
+            fp = batch_footprint(batch, self.engine.num_records) \
+                if self.conflict_aware else None
         self._admission.append(_Admitted(ticket, batch, fp, rank,
                                          t_admit=time.monotonic()))
         self.stats["submitted"] += 1
@@ -267,7 +275,8 @@ class TxnService:
         retrieval consumes the ticket."""
         self._pump(flush=True)
         res = self._results.pop(ticket)
-        jax.block_until_ready(res.read_vals)
+        with self.tracer.span("service/wait", ticket=ticket):
+            jax.block_until_ready(res.read_vals)
         self._note_joined(ticket)
         if self.flight.enabled:
             self.flight.on_visible(ticket)
@@ -348,11 +357,13 @@ class TxnService:
         """Bound the unrealised exec-step queue by joining the oldest
         epoch (any one of its results realises the whole step)."""
         while len(self._inflight) > self.max_inflight:
-            oldest = self._inflight.popleft()
+            epoch, oldest = self._inflight.popleft()
             for ticket in oldest:
                 res = self._results.get(ticket)
                 if res is not None:
-                    jax.block_until_ready(res.read_vals)
+                    with self.tracer.span("service/backpressure",
+                                          epoch=epoch):
+                        jax.block_until_ready(res.read_vals)
                     self.stats["backpressure_joins"] += 1
                     break
 
@@ -369,7 +380,9 @@ class TxnService:
                     and not any(a.latency_class == 0
                                 for a in self._admission)):
                 break        # hold: wait for merge candidates
-            tickets, sizes, batch, fp = self._pop_epoch()
+            epoch = len(self.dispatch_log)
+            with self.tracer.span("service/form_epoch", epoch=epoch):
+                tickets, sizes, batch, fp = self._pop_epoch()
             # the watermark (and pin set) the dispatch-order sequential
             # schedule would use for this epoch, captured at plan time
             # (the ts mirror equals this epoch's ts base here) so
@@ -382,16 +395,16 @@ class TxnService:
             wm = eng.watermark()
             pins = eng.pin_array()
             ts_base, _ = eng.claim_ts_window(batch.size)
-            with self.tracer.span("plan_phase", txns=batch.size,
-                                  epoch_batches=len(tickets)) as sp:
-                plan = sp.fence(
-                    eng._plan(batch, jnp.asarray(ts_base, jnp.int32)))
+            with self.tracer.span("service/plan", epoch=epoch,
+                                  txns=batch.size,
+                                  epoch_batches=len(tickets)):
+                plan = eng._plan(batch, jnp.asarray(ts_base, jnp.int32))
             self._planned.append(_Planned(tickets, sizes, batch, fp,
-                                          plan, ts_base, wm, pins))
+                                          plan, ts_base, wm, pins, epoch))
             self.dispatch_log.append(list(tickets))
             if self.flight.enabled:
                 self.flight.on_dispatch(
-                    tickets, epoch=len(self.dispatch_log) - 1,
+                    tickets, epoch=epoch,
                     epoch_txns=batch.size, epoch_batches=len(tickets))
             self.stats["planned_ahead_max"] = max(
                 self.stats["planned_ahead_max"], len(self._planned))
@@ -669,9 +682,9 @@ class TxnService:
     def _exec_epoch(self, e: _Planned, overlapped: bool = False,
                     chain_depth: int = 1):
         kwargs = {"overlapped": True} if overlapped else {}
-        with self.tracer.span("exec_phase", txns=e.size, **kwargs) as sp:
+        with self.tracer.span("service/exec", epoch=e.epoch, txns=e.size,
+                              **kwargs):
             w, r, m = self.engine._exec(e.plan, e.batch, self.engine.store)
-            sp.fence(r)
         if self.flight.enabled:
             self.flight.on_exec(e.tickets, chain_depth)
         return w, r, m
@@ -685,13 +698,12 @@ class TxnService:
         eng = self.engine
         window = (jnp.asarray(e.ts_base, jnp.int32),
                   jnp.asarray(e.ts_base + e.size, jnp.int32))
-        with self.tracer.span("commit_phase", txns=e.size,
-                              epoch_batches=len(e.tickets)) as sp:
+        with self.tracer.span("service/commit", epoch=e.epoch,
+                              txns=e.size, epoch_batches=len(e.tickets)):
             store, ring_metrics = eng._commit(
                 e.plan, e.batch, eng.store, w_data,
                 jnp.asarray(e.watermark, jnp.int32), window, e.pin_ts)
             eng.store = store
-            sp.fence(store.base)
         if self.flight.enabled:
             self.flight.on_commit(e.tickets)
         metrics = dict(exec_metrics, **ring_metrics)
@@ -702,14 +714,14 @@ class TxnService:
                 else read_vals[off:off + size]
             self._results[ticket] = BatchResult(ticket, rv, metrics)
             off += size
-        self._inflight.append(list(e.tickets))
+        self._inflight.append((e.epoch, list(e.tickets)))
         if not self.pipelined:
             jax.block_until_ready(store.base)
             self._inflight.clear()
 
     def _note_joined(self, ticket: int) -> None:
         """A realised ticket realises its whole epoch's exec step."""
-        for i, epoch_tickets in enumerate(self._inflight):
+        for i, (_, epoch_tickets) in enumerate(self._inflight):
             if ticket in epoch_tickets:
                 del self._inflight[i]
                 return

@@ -74,7 +74,7 @@ class ServeEngine:
         # through the full CC->exec->commit pipeline each serving step and
         # read back via batched snapshot reads over the sharded ring.
         # registry/tracer flow into the state engine, so lookup /
-        # progress_view snapshot reads show up as "read/resolve" spans
+        # progress_view snapshot reads show up as "engine/readonly" spans
         # next to the store's plan/exec/commit phases.
         self.max_rids = max_rids
         self.state = BohmEngine(max_rids, make_state_workload(),

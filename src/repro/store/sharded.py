@@ -316,41 +316,44 @@ def _commit_one_shard(ring_s, spill_s: Optional[SpillPool],
     with_spill = spill_s is not None
     commit_fn = commit_paged if isinstance(ring_s, PageSlab) \
         else commit_versions
-    ring_o, m = commit_fn(ring_s, rec_l, key_l, owned, w_begin_ts,
-                          w_end_ts, w_data, watermark,
-                          ts_window=ts_window, k_eff=k_eff_s,
-                          pin_ts=pin_ts, with_evictees=with_spill,
-                          with_audit=with_audit)
+    with jax.named_scope("commit/ring"):
+        ring_o, m = commit_fn(ring_s, rec_l, key_l, owned, w_begin_ts,
+                              w_end_ts, w_data, watermark,
+                              ts_window=ts_window, k_eff=k_eff_s,
+                              pin_ts=pin_ts, with_evictees=with_spill,
+                              with_audit=with_audit)
     if with_spill:
-        ev = {k: m.pop(k) for k in _EVICT_KEYS}
-        wm = jnp.asarray(watermark, jnp.int32)
-        if ts_window is not None:
-            wm = jnp.minimum(wm, jnp.asarray(ts_window[0], jnp.int32))
-        spill_s, sm = spill_commit(spill_s, ev["evict_rec"],
-                                   ev["evict_begin"], ev["evict_end"],
-                                   ev["evict_payload"], ev["evict_valid"],
-                                   wm, pin_ts=pin_ts,
-                                   with_audit=with_audit)
-        if with_audit:
-            placed = sm.pop("spill_audit_placed")
-            v_valid = sm.pop("spill_victim_valid")
-            v_rec = sm.pop("spill_victim_rec")
-            v_begin = sm.pop("spill_victim_begin")
-            v_end = sm.pop("spill_victim_end")
-            offered = ev["evict_valid"]
-            sp_state = jnp.where(placed, AUDIT_SPILLED,
-                                 jnp.where(offered, AUDIT_SPILL_DROPPED, 0))
-            vic_state = jnp.where(v_valid, AUDIT_SPILL_OVERWROTE, 0)
-            m["audit_rec"] = jnp.concatenate(
-                [m["audit_rec"], ev["evict_rec"], v_rec])
-            m["audit_begin"] = jnp.concatenate(
-                [m["audit_begin"], ev["evict_begin"], v_begin])
-            m["audit_end"] = jnp.concatenate(
-                [m["audit_end"], ev["evict_end"], v_end])
-            m["audit_state"] = jnp.concatenate(
-                [m["audit_state"], sp_state.astype(jnp.int32),
-                 vic_state.astype(jnp.int32)])
-        m.update(sm)
+        with jax.named_scope("commit/spill"):
+            ev = {k: m.pop(k) for k in _EVICT_KEYS}
+            wm = jnp.asarray(watermark, jnp.int32)
+            if ts_window is not None:
+                wm = jnp.minimum(wm, jnp.asarray(ts_window[0], jnp.int32))
+            spill_s, sm = spill_commit(spill_s, ev["evict_rec"],
+                                       ev["evict_begin"], ev["evict_end"],
+                                       ev["evict_payload"], ev["evict_valid"],
+                                       wm, pin_ts=pin_ts,
+                                       with_audit=with_audit)
+            if with_audit:
+                placed = sm.pop("spill_audit_placed")
+                v_valid = sm.pop("spill_victim_valid")
+                v_rec = sm.pop("spill_victim_rec")
+                v_begin = sm.pop("spill_victim_begin")
+                v_end = sm.pop("spill_victim_end")
+                offered = ev["evict_valid"]
+                sp_state = jnp.where(
+                    placed, AUDIT_SPILLED,
+                    jnp.where(offered, AUDIT_SPILL_DROPPED, 0))
+                vic_state = jnp.where(v_valid, AUDIT_SPILL_OVERWROTE, 0)
+                m["audit_rec"] = jnp.concatenate(
+                    [m["audit_rec"], ev["evict_rec"], v_rec])
+                m["audit_begin"] = jnp.concatenate(
+                    [m["audit_begin"], ev["evict_begin"], v_begin])
+                m["audit_end"] = jnp.concatenate(
+                    [m["audit_end"], ev["evict_end"], v_end])
+                m["audit_state"] = jnp.concatenate(
+                    [m["audit_state"], sp_state.astype(jnp.int32),
+                     vic_state.astype(jnp.int32)])
+            m.update(sm)
     return ring_o, spill_s, m
 
 
@@ -385,8 +388,17 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
     with_spill = store.spill is not None
     paged = store.paged
     if n == 1:
+        # the shard split and restack serve the stage whose state they
+        # carry: the primary's under commit/ring, the pool's under
+        # commit/spill
+        with jax.named_scope("commit/ring"):
+            prim0 = _ring0(store)
+        with jax.named_scope("commit/spill"):
+            spill_in = _take_spill(store, 0)
+        with jax.named_scope("commit/ring"):
+            k_eff0 = store.k_eff[0]
         prim, spill0, metrics = _commit_one_shard(
-            _ring0(store), _take_spill(store, 0), store.k_eff[0],
+            prim0, spill_in, k_eff0,
             w_rec, w_key, w_valid, w_begin_ts, w_end_ts, w_data,
             watermark, ts_window, pin_ts, with_audit=with_audit)
         for k in ("ring_overwrote_rec", "ring_overwrote_dead_rec"):
@@ -394,15 +406,18 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         if with_audit:
             metrics["audit_rec"] = jnp.where(
                 metrics["audit_state"] > 0, metrics["audit_rec"], -1)
-        new_spill = None if spill0 is None else jax.tree.map(
-            lambda x: x[None], spill0)
-        return dataclasses.replace(
-            _with_primary(store, jax.tree.map(lambda x: x[None], prim)),
-            spill=new_spill), metrics
+        with jax.named_scope("commit/spill"):
+            new_spill = None if spill0 is None else jax.tree.map(
+                lambda x: x[None], spill0)
+        with jax.named_scope("commit/ring"):
+            prim = jax.tree.map(lambda x: x[None], prim)
+        return dataclasses.replace(_with_primary(store, prim),
+                                   spill=new_spill), metrics
 
     def one_shard(prim_s, spill_s, k_eff_s, shard):
-        rec_l, key_l, owned = _mask_to_shard(n, shard, w_rec, w_key,
-                                             w_valid)
+        with jax.named_scope("commit/ring"):
+            rec_l, key_l, owned = _mask_to_shard(n, shard, w_rec, w_key,
+                                                 w_valid)
         return _commit_one_shard(prim_s, spill_s, k_eff_s, rec_l, key_l,
                                  owned, w_begin_ts, w_end_ts, w_data,
                                  watermark, ts_window, pin_ts,
@@ -413,12 +428,19 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
 
         def body(prim, spill, k_eff):
             squeeze = lambda t: jax.tree.map(lambda x: x[0], t)  # noqa: E731
-            prim_o, spill_o, m = one_shard(squeeze(prim),
-                                           None if spill is None
-                                           else squeeze(spill),
-                                           k_eff[0],
-                                           jax.lax.axis_index(axis))
-            return jax.tree.map(lambda x: x[None], (prim_o, spill_o, m))
+            with jax.named_scope("commit/ring"):
+                prim_s = squeeze(prim)
+            with jax.named_scope("commit/spill"):
+                spill_s = None if spill is None else squeeze(spill)
+            with jax.named_scope("commit/ring"):
+                k_eff_s = k_eff[0]
+                shard = jax.lax.axis_index(axis)
+            prim_o, spill_o, m = one_shard(prim_s, spill_s, k_eff_s, shard)
+            with jax.named_scope("commit/ring"):
+                prim_o = jax.tree.map(lambda x: x[None], prim_o)
+            with jax.named_scope("commit/spill"):
+                spill_o = jax.tree.map(lambda x: x[None], spill_o)
+            return prim_o, spill_o, jax.tree.map(lambda x: x[None], m)
 
         out_struct = (_page_struct() if paged else _ring_struct(),
                       None if not with_spill else _spill_struct(),
@@ -667,13 +689,16 @@ def _resolve_two_level(prim_s, spill_s: Optional[SpillPool],
     slots); both resolve through the same ``mvcc_resolve`` kernel."""
     gather = gather_windows_paged if isinstance(prim_s, PageSlab) \
         else gather_windows
-    vals, found = ops.mvcc_resolve(*gather(prim_s, local_rec), ts)
+    with jax.named_scope("resolve/gather"):
+        windows = gather(prim_s, local_rec)
+    vals, found = ops.mvcc_resolve(*windows, ts)
     if spill_s is None:
         return vals, found
-    bkt = spill_buckets_for(local_rec, spill_s.begin.shape[0])
-    s_vals, s_found = ops.mvcc_resolve_masked(
-        spill_s.begin[bkt], spill_s.end[bkt], spill_s.rec[bkt],
-        local_rec, spill_s.payload[bkt], ts)
+    with jax.named_scope("resolve/gather"):
+        bkt = spill_buckets_for(local_rec, spill_s.begin.shape[0])
+        s_windows = (spill_s.begin[bkt], spill_s.end[bkt],
+                     spill_s.rec[bkt], local_rec, spill_s.payload[bkt])
+    s_vals, s_found = ops.mvcc_resolve_masked(*s_windows, ts)
     return jnp.where(found[:, None], vals, s_vals), found | s_found
 
 
@@ -690,12 +715,14 @@ def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
     records = jnp.asarray(records, jnp.int32)
     if n == 1:
         local = jnp.maximum(records, 0)
-        return _resolve_two_level(_ring0(store), _take_spill(store, 0),
-                                  local, ts)
+        with jax.named_scope("resolve/gather"):
+            prim0, spill0 = _ring0(store), _take_spill(store, 0)
+        return _resolve_two_level(prim0, spill0, local, ts)
 
     def one_shard(prim_s, spill_s, shard):
-        owned = (records % n) == shard
-        local = jnp.where(owned, records // n, 0)
+        with jax.named_scope("resolve/gather"):
+            owned = (records % n) == shard
+            local = jnp.where(owned, records // n, 0)
         vals, found = _resolve_two_level(prim_s, spill_s, local, ts)
         return jnp.where(owned[:, None], vals, 0), owned & found
 
@@ -704,9 +731,10 @@ def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
 
         def body(prim, spill):
             squeeze = lambda t: jax.tree.map(lambda x: x[0], t)  # noqa: E731
-            vals, found = one_shard(squeeze(prim),
-                                    None if spill is None
-                                    else squeeze(spill),
+            with jax.named_scope("resolve/gather"):
+                prim_s = squeeze(prim)
+                spill_s = None if spill is None else squeeze(spill)
+            vals, found = one_shard(prim_s, spill_s,
                                     jax.lax.axis_index(axis))
             # each read is owned by exactly one shard: sum == select
             return (jax.lax.psum(vals, axis),
@@ -724,8 +752,9 @@ def resolve_sharded(store: ShardedVersionStore, records: jax.Array,
     vals = None
     found = None
     for s in range(n):
-        v_s, f_s = one_shard(_take_shard(store, s), _take_spill(store, s),
-                             jnp.int32(s))
+        with jax.named_scope("resolve/gather"):
+            prim_s, spill_s = _take_shard(store, s), _take_spill(store, s)
+        v_s, f_s = one_shard(prim_s, spill_s, jnp.int32(s))
         vals = v_s if vals is None else vals + v_s
         found = f_s if found is None else found | f_s
     return vals, found

@@ -1,9 +1,7 @@
-"""Flight recorder (repro.obs.flight) + trajectory gate (repro.obs
-.regress): the zero-sync contract (recorder off OR on adds ZERO fences
-and leaves results byte-identical), the telescoping latency breakdown,
-randomized conflict-witness soundness, async-lane Chrome-trace
-invariants, streaming quantile accuracy, and the EWMA regression gate's
-direction handling."""
+"""Flight recorder (repro.obs.flight): the zero-sync contract (recorder
+off OR on adds ZERO fences and leaves results byte-identical), the
+telescoping latency breakdown, randomized conflict-witness soundness,
+async-lane Chrome-trace invariants and streaming quantile accuracy."""
 import json
 
 import jax
@@ -16,9 +14,8 @@ from repro.core.plan import (batch_footprint, conflict_witness,
                              footprints_conflict)
 from repro.core.txn import Workload, make_batch
 from repro.obs import (NULL_FLIGHT, FlightRecorder, LogHistogram,
-                       PhaseTracer, append_entry, check_history,
-                       direction_for, history_path, load_history,
-                       stitch_chrome_trace, validate_chrome_trace)
+                       PhaseTracer, stitch_chrome_trace,
+                       validate_chrome_trace)
 from repro.service import TxnService
 
 T, OPS, R = 16, 3, 64
@@ -257,48 +254,3 @@ def test_log_histogram_tracks_numpy_percentiles():
     # round-trips through its dict form
     back = LogHistogram.from_dict(h.to_dict())
     assert back.quantile(50.0) == h.quantile(50.0)
-
-
-# ----------------------------------------------------- trajectory gate
-def test_regress_directions():
-    assert direction_for("txn_s") == "higher"
-    assert direction_for("vs_barriered") == "higher"
-    assert direction_for("p99_ms") == "lower"
-    assert direction_for("us_per_txn") == "lower"
-    assert direction_for("found_rate") == "higher"
-
-
-def test_regress_gate_flags_newest_entry(tmp_path):
-    path = history_path("demo", str(tmp_path))
-    for _ in range(5):
-        append_entry(path, "demo",
-                     {"txn_s": 1000.0, "p99_ms": 4.0}, meta={"git": "x"})
-    assert check_history(load_history(path)) == []    # steady: no flags
-
-    # throughput collapse (higher-better) + latency blowup (lower-better)
-    append_entry(path, "demo", {"txn_s": 200.0, "p99_ms": 40.0},
-                 meta={"git": "y"})
-    regs = check_history(load_history(path))
-    assert {r.metric for r in regs} == {"txn_s", "p99_ms"}
-    for r in regs:
-        assert r.ratio > 1.5 and r.suite == "demo"
-        assert "demo/" in r.describe()
-
-    # an IMPROVEMENT must not be flagged
-    path2 = history_path("demo2", str(tmp_path))
-    for v in (1000.0, 1000.0, 1000.0, 5000.0):
-        append_entry(path2, "demo2", {"txn_s": v}, meta={})
-    assert check_history(load_history(path2)) == []
-
-
-def test_regress_history_bounded_and_stamped(tmp_path):
-    path = history_path("cap", str(tmp_path))
-    for i in range(8):
-        append_entry(path, "cap", {"m_us": float(i)}, max_entries=5)
-    hist = load_history(path)
-    assert len(hist["entries"]) == 5
-    assert [e["metrics"]["m_us"] for e in hist["entries"]] == \
-        [3.0, 4.0, 5.0, 6.0, 7.0]
-    # default meta is the provenance stamp
-    assert "jax_version" in hist["entries"][-1]["meta"]
-    assert "git_sha" in hist["entries"][-1]["meta"]

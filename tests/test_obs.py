@@ -1,9 +1,11 @@
-"""Observability plane (repro.obs): registry semantics, tracing with
-zero overhead when disabled, Chrome-trace export invariants, EWMA
-regression (the ft.monitor extraction), provenance stamping, health
-gauges — and the non-perturbation properties: instrumentation must
-leave engine/service results byte-identical, and the pipelined vs
-barriered schedules must agree on every data counter."""
+"""Observability plane (repro.obs): registry semantics, unfenced tracing
+(no host sync whether it records, annotates or neither), the spans'
+names and epoch arguments on the profiler's annotations, Chrome-trace
+export invariants, EWMA regression (the ft.monitor extraction),
+provenance stamping, health gauges — and the non-perturbation
+properties: instrumentation must leave engine/service results
+byte-identical, and the pipelined vs barriered schedules must agree on
+every data counter."""
 import json
 
 import jax
@@ -108,7 +110,6 @@ def test_tracer_disabled_is_null_span_and_records_nothing():
     sp = tr.span("plan_phase", txns=8)
     assert sp is NULL_SPAN
     with sp as s:
-        assert s.fence(123) == 123               # passthrough
         s.note(k=1)
     tr.instant("decision", x=1)
     assert tr.events() == []
@@ -116,8 +117,11 @@ def test_tracer_disabled_is_null_span_and_records_nothing():
 
 
 def test_tracer_disabled_never_blocks(monkeypatch):
-    """The zero-overhead-when-off property: a full run_batch stream with
-    tracing disabled performs ZERO block_until_ready fences."""
+    """The zero-sync property: a run_batch stream performs ZERO
+    block_until_ready fences with the default tracer, and so does a
+    tracer that records AND annotates — through the engine and through
+    the service's dispatch path, whose results stay byte-identical to an
+    untraced service's."""
     calls = {"n": 0}
     real = jax.block_until_ready
 
@@ -126,20 +130,41 @@ def test_tracer_disabled_never_blocks(monkeypatch):
         return real(x)
 
     eng = BohmEngine(R, _inc_workload(), ring_slots=8)
-    assert not eng.tracer.enabled
+    assert not eng.tracer.enabled and eng.tracer.annotate
     batches = [_random_batch(s) for s in range(3)]
     monkeypatch.setattr(jax, "block_until_ready", counting)
     for b in batches:
         eng.run_batch(b)
     eng.gc_sweep()
     assert calls["n"] == 0
-    # ... and enabling tracing is what introduces the fences
+    # tracing on (ring + annotations) adds no fence either
     eng2 = BohmEngine(R, _inc_workload(), ring_slots=8,
-                      tracer=PhaseTracer(enabled=True))
-    calls["n"] = 0
-    monkeypatch.setattr(jax, "block_until_ready", counting)
-    eng2.run_batch(batches[0])
-    assert calls["n"] > 0
+                      tracer=PhaseTracer(enabled=True, annotate=True))
+    for b in batches:
+        eng2.run_batch(b)
+    eng2.run_readonly_batch(batches[0], 1)
+    eng2.gc_sweep()
+    assert calls["n"] == 0
+    assert validate_chrome_trace(eng2.tracer.to_chrome_trace())["spans"] > 0
+
+    def served(tracer):
+        e = BohmEngine(R, _inc_workload(), ring_slots=8, tracer=tracer)
+        svc = TxnService(e, max_inflight=2, admission_window=2)
+        tickets = [svc.submit(b) for b in batches * 2]
+        calls["n"] = 0
+        svc._pump(flush=True)                # the dispatch path alone
+        dispatched = calls["n"]
+        reads = [np.asarray(svc.wait(t).read_vals) for t in tickets]
+        svc.drain()
+        return dispatched, reads, np.asarray(e.store.base)
+
+    n_on, reads_on, base_on = served(PhaseTracer(enabled=True,
+                                                 annotate=True))
+    n_off, reads_off, base_off = served(PhaseTracer(enabled=False))
+    assert n_on == n_off
+    for a, b in zip(reads_on, reads_off):
+        np.testing.assert_array_equal(a, b)
+    np.testing.assert_array_equal(base_on, base_off)
 
 
 def test_tracer_span_export_and_validation(tmp_path):
@@ -181,23 +206,100 @@ def test_tracer_ring_overflow_export_stays_valid():
     assert tr.events() == [] and tr.dropped == 0
 
 
-def test_tracer_span_fence_blocks_lazy_value():
-    tr = PhaseTracer(enabled=True)
-    x = jnp.arange(8) * 2
-    with tr.span("phase") as sp:
-        y = sp.fence(x + 1)
-    np.testing.assert_array_equal(np.asarray(y), np.arange(8) * 2 + 1)
+class _StubAnnotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each span's
+    name and arguments and whether it was entered and left."""
+    log: list = []
+
+    def __init__(self, name, **kwargs):
+        self.entry = [name, kwargs, "made"]
+        _StubAnnotation.log.append(self.entry)
+
+    def __enter__(self):
+        self.entry[2] = "open"
+        return self
+
+    def __exit__(self, *exc):
+        self.entry[2] = "closed"
+        return False
 
 
-def test_tracer_anomaly_flagging():
-    tr = PhaseTracer(enabled=True, anomaly_alpha=1.0,
-                     anomaly_threshold=2.0)
-    # drive _flag_anomaly directly: baseline seeds at 1.0; 3.0 is > 2x
-    assert tr._flag_anomaly("p", 1.0) is False
-    assert tr._flag_anomaly("p", 3.0) is True
-    assert tr.anomalies == {"p": 1}
-    # flagged sample did not move the baseline (still 1.0)
-    assert tr._flag_anomaly("p", 1.9) is False
+@pytest.fixture
+def annotations(monkeypatch):
+    """Every span name and argument dict the tracers built after this
+    fixture annotated."""
+    _StubAnnotation.log = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _StubAnnotation)
+    return _StubAnnotation.log
+
+
+def test_tracer_annotates_without_recording(annotations):
+    """``annotate`` no longer depends on ``enabled``: an annotating
+    tracer with the ring off hands every span to the profiler and
+    records nothing; with both off nothing is annotated."""
+    tr = PhaseTracer(enabled=False, annotate=True)
+    with tr.span("service/plan", epoch=3, txns=8) as sp:
+        sp.note(k=1)
+    tr.instant("admission/hop", x=1)
+    assert annotations == [["service/plan", {"epoch": 3, "txns": 8},
+                            "closed"]]
+    assert tr.events() == [] and tr.dropped == 0
+    rec = PhaseTracer(enabled=True, annotate=True)
+    with rec.span("engine/gc_sweep", watermark=5):
+        pass
+    assert annotations[-1] == ["engine/gc_sweep", {"watermark": 5},
+                               "closed"]
+    assert [e[:2] for e in rec.events()] == [("B", "engine/gc_sweep"),
+                                             ("E", "engine/gc_sweep")]
+    with PhaseTracer(enabled=False, annotate=False).span("x") as sp:
+        assert sp is NULL_SPAN
+    assert len(annotations) == 2
+
+
+def test_service_spans_carry_their_epoch(annotations):
+    """The default tracer annotates the service's host path: admission,
+    epoch formation, the three dispatches and both joins, and every span
+    of an epoch carries that epoch's ``dispatch_log`` index."""
+    eng = BohmEngine(R, _inc_workload(), ring_slots=8)
+    svc = TxnService(eng, max_inflight=1, admission_window=2)
+    tickets = svc.submit_many([_random_batch(s) for s in range(6)])
+    for t in tickets:
+        svc.wait(t)
+    svc.drain()
+    assert all(state == "closed" for _, _, state in annotations)
+    names = {name for name, _, _ in annotations}
+    assert {"service/admit", "service/form_epoch", "service/plan",
+            "service/exec", "service/commit", "service/backpressure",
+            "service/wait"} <= names
+    assert not names & {"plan_phase", "exec_phase", "commit_phase"}
+    by_name = {}
+    for name, args, _ in annotations:
+        by_name.setdefault(name, []).append(args)
+    n_epochs = len(svc.dispatch_log)
+    for name in ("service/form_epoch", "service/plan", "service/exec",
+                 "service/commit"):
+        assert [a["epoch"] for a in by_name[name]] == list(range(n_epochs))
+    for args in by_name["service/plan"]:
+        epoch = svc.dispatch_log[args["epoch"]]
+        assert args["epoch_batches"] == len(epoch)
+        assert args["txns"] == T * len(epoch)
+    assert all(0 <= a["epoch"] < n_epochs
+               for a in by_name["service/backpressure"])
+    assert [a["ticket"] for a in by_name["service/admit"]] == tickets
+    assert [a["ticket"] for a in by_name["service/wait"]] == tickets
+
+
+def test_engine_spans_on_the_profiler(annotations):
+    eng = BohmEngine(R, _inc_workload(), ring_slots=2, adaptive_k=True)
+    for s in range(3):
+        eng.run_batch(_random_batch(s))
+    eng.run_readonly_batch(_random_batch(7), eng.current_ts())
+    eng.gc_sweep()
+    names = [name for name, _, _ in annotations]
+    assert names[:3] == ["engine/plan", "engine/exec", "engine/commit"]
+    assert {"engine/readonly", "engine/gc_sweep",
+            "engine/reassign_k"} <= set(names)
+    assert not set(names) & {"read/resolve", "gc_sweep", "reassign_k"}
 
 
 def test_validate_chrome_trace_rejects_malformed():
