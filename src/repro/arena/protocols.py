@@ -238,7 +238,7 @@ class BohmProtocol(ProtocolEngine):
 
     def finish(self) -> jax.Array:
         self.service.drain()
-        return self.engine.store.base
+        return self.engine.snapshot()
 
     def run_scan(self, batch: TxnBatch) -> jax.Array:
         """The zero-bookkeeping read path: pin a snapshot, resolve the

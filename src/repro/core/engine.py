@@ -211,7 +211,11 @@ class BohmEngine:
                                cc_axis=cc_axis)
         self._plan = jit_named(plan_phase, mesh=mesh, cc_axis=cc_axis)
         self._exec = jit_named(exec_phase, workload=workload)
-        self._commit = jit_named(commit_phase, mesh=mesh, cc_axis=cc_axis,
+        # the commit takes the store by donation and updates it in place:
+        # every earlier dispatch that reads the store is already enqueued,
+        # and no value handed out of the engine is a store leaf
+        self._commit = jit_named(commit_phase, donate_argnums=(2,),
+                                 mesh=mesh, cc_axis=cc_axis,
                                  with_audit=self.auditor.enabled)
         self._gc = jax.jit(gc_sharded)
         self._gc_audit = jit_named(gc_sharded_audited,
@@ -289,14 +293,16 @@ class BohmEngine:
         return metrics
 
     def snapshot(self) -> jax.Array:
+        """The committed head state [R, D], as a copy: the store's own
+        arrays are donated to the next commit."""
         if self.auditor.enabled:
             self.auditor.harvest()
-        return self.store.base
+        return jnp.copy(self.store.base)
 
     def reset_store(self, base: jax.Array,
                     base_ts: Optional[jax.Array] = None) -> None:
         """Reinitialise committed state (head cache + rings + spill) from
-        ``base``."""
+        ``base`` (copied: the caller's array stays its own)."""
         self.store = store_from_base(base, base_ts, self.k_max,
                                      self.n_shards,
                                      spill_buckets=self.spill_buckets,

@@ -77,10 +77,12 @@ def store_from_base(base: jax.Array, base_ts: Optional[jax.Array] = None,
                     k_init: Optional[int] = None,
                     paged: bool = False, page_slots: int = 4,
                     pages_per_shard: Optional[int] = None) -> Store:
-    """Store whose initial state (head + ring slot 0) is ``base``."""
-    base = jnp.asarray(base, jnp.int32)
-    if base_ts is None:
-        base_ts = jnp.zeros((base.shape[0],), jnp.int32)
+    """Store whose initial state (head + ring slot 0) is ``base``. The head
+    is a copy of ``base`` (and of ``base_ts``): the commit updates the
+    store's arrays in place, which must not reach the caller's."""
+    base = jnp.array(base, jnp.int32, copy=True)
+    base_ts = (jnp.zeros((base.shape[0],), jnp.int32) if base_ts is None
+               else jnp.array(base_ts, jnp.int32, copy=True))
     return Store(base=base, base_ts=base_ts,
                  ts_counter=jnp.ones((), jnp.int32),
                  versions=init_sharded_store(base, base_ts, ring_slots,
@@ -206,16 +208,13 @@ def commit(plan: Plan, batch: TxnBatch, store: Store, w_data: jax.Array,
     # ``commit/spill`` in the store) name the ops in the program's
     # metadata only; the device trace reads each stage's time from them
     with jax.named_scope("commit/head"):
-        rec = jnp.where(plan.commit_mask, plan.w_rec, R)      # drop pads
-        base = jnp.concatenate([store.base,
-                                jnp.zeros((1,) + store.base.shape[1:],
-                                          store.base.dtype)])
-        base = base.at[rec].set(w_data, mode="drop")[:-1]
+        # one batch-final version per record; pads index past the end and
+        # drop, so both scatters write the (donated) head in place
+        rec = jnp.where(plan.commit_mask, plan.w_rec, R)
+        base = store.base.at[rec].set(w_data, mode="drop")
         ts = plan.ts_base + plan.w_txn
-        base_ts = jnp.concatenate([store.base_ts,
-                                   jnp.zeros((1,), jnp.int32)])
-        base_ts = base_ts.at[rec].set(jnp.where(plan.commit_mask, ts, 0),
-                                      mode="drop")[:-1]
+        base_ts = store.base_ts.at[rec].set(
+            jnp.where(plan.commit_mask, ts, 0), mode="drop")
     versions, ring_metrics = commit_sharded(
         store.versions, plan.w_rec, plan.w_key, plan.w_valid,
         plan.w_begin_ts, plan.w_end_ts, w_data, watermark,

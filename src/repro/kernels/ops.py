@@ -17,6 +17,7 @@ from repro.kernels.mvcc_resolve import default_interpret as _interpret
 from repro.kernels.mvcc_resolve import mvcc_resolve as _resolve
 from repro.kernels.mvcc_resolve import \
     mvcc_resolve_masked as _resolve_masked
+from repro.kernels.row_update import update_rows as _update_rows
 
 
 def mvcc_resolve(begin, end, data, ts, **kw):
@@ -28,6 +29,12 @@ def mvcc_resolve_masked(begin, end, rec, want, data, ts, **kw):
     # the spill-pool fall-through: shared bucket windows filtered by
     # owner record id inside the visibility test
     return _resolve_masked(begin, end, rec, want, data, ts, **kw)
+
+
+def update_rows(x, rec, slot, keep, rows, **kw):
+    # the commit's in-place write of version rows into a record-minor
+    # ring or spill payload (interpret mode picked inside the module)
+    return _update_rows(x, rec, slot, keep, rows, **kw)
 
 
 def decode_attention(q, k, v, kv_len, **kw):

@@ -56,6 +56,16 @@ def mvcc_resolve_paged_ref(page_rows: jax.Array, begin: jax.Array,
                             w_data.reshape(b, maxp * s, -1), ts)
 
 
+def update_rows_ref(x: jax.Array, rec: jax.Array, slot: jax.Array,
+                    keep: jax.Array, rows: jax.Array):
+    """``x`` [R, K, D] with row (rec, slot) set to ``rows`` where ``keep``,
+    and the rows it held before (0 where not kept)."""
+    r, k = jnp.where(keep, rec, 0), jnp.where(keep, slot, 0)
+    old = jnp.where(keep[:, None], x[r, k], 0)
+    put = jnp.where(keep, rec, x.shape[0])
+    return x.at[put, slot].set(rows, mode="drop"), old
+
+
 def decode_attention_ref(q: jax.Array, k: jax.Array, v: jax.Array,
                          kv_len: jax.Array) -> jax.Array:
     """q [B,KvH,G,Dh]; k,v [B,T,KvH,Dh]; kv_len [B] or scalar."""

@@ -41,18 +41,21 @@ def cc_mesh(n: Optional[int] = None,
     return make_mesh((len(devices),), ("cc",), devices=devices)
 
 
-def jit_named(fn: Callable, **bound) -> Callable:
+def jit_named(fn: Callable, donate_argnums: Sequence[int] = (),
+              **bound) -> Callable:
     """``jax.jit`` of ``fn`` with the keyword arguments ``bound`` fixed,
     its program named ``jit_<fn name>`` on the device and in the
     profiler's trace (a bare ``functools.partial`` is named
     ``jit__unknown``). Only the name is copied: a ``__wrapped__``
     (``functools.wraps``) would make JAX bind the arguments against
     ``fn``'s own signature, fail on the bound keywords, and rename the
-    program's parameters."""
+    program's parameters. ``donate_argnums`` passes through to
+    ``jax.jit``: the program may write those positional arguments'
+    buffers in place, and the caller's arrays are deleted by the call."""
     phase = functools.partial(fn, **bound)
     phase.__name__ = fn.__name__
     phase.__qualname__ = fn.__qualname__
-    return jax.jit(phase)
+    return jax.jit(phase, donate_argnums=tuple(donate_argnums))
 
 
 def setup_compile_cache() -> str:

@@ -55,6 +55,8 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+
 INF_TS = jnp.iinfo(jnp.int32).max
 
 # Version-lifecycle audit state codes. They are defined HERE (not in
@@ -240,11 +242,20 @@ def commit_versions(ring: VersionRing, w_rec: jax.Array, w_key: jax.Array,
     drop_n = jnp.maximum(count - k_rec, 0)         # overflow: drop oldest
     keep = valid_s & (rank >= drop_n)
     slot = (ring.head[safe_rec] + rank - drop_n) % k_rec
-    flat = jnp.where(keep, safe_rec * K + slot, R * K)   # OOB => dropped
-
-    safe_flat = jnp.minimum(flat, R * K - 1)
-    tgt_begin = begin.reshape(-1)[safe_flat]
-    tgt_end = end.reshape(-1)[safe_flat]
+    # the ring is donated to the commit and written in place: begin and
+    # end are read and scattered by (record, slot) pairs in their stored
+    # [R, K] shape (a flattened [R * K] view is a relayout on a tiled
+    # device), and the payload rows go through the in-place row kernel,
+    # which also returns the rows it overwrites. Dropped entries read the
+    # last slot and write to record R, out of range.
+    put_rec = jnp.where(keep, safe_rec, R)
+    get_rec = jnp.where(keep, safe_rec, R - 1)
+    get_slot = jnp.where(keep, slot, K - 1)
+    tgt_begin = begin[get_rec, get_slot]
+    tgt_end = end[get_rec, get_slot]
+    payload, tgt_payload = ops.update_rows(ring.payload, safe_rec, slot,
+                                           keep, data_s,
+                                           with_old=with_evictees)
     # liveness of what this insert destroys: pin-precise — a registered
     # snapshot pin inside [begin, end), or end reaching the future-reader
     # floor. Versions superseded between the lowest pin and "now" stab no
@@ -268,10 +279,9 @@ def commit_versions(ring: VersionRing, w_rec: jax.Array, w_key: jax.Array,
                                                          pin_ts))
 
     if with_evictees:
-        # old contents of the slots this insert destroys, gathered BEFORE
-        # the scatter (targets are distinct, so pre-scatter state is the
+        # old contents of the slots this insert destroys, read before they
+        # are written (targets are distinct, so pre-write state is the
         # pre-batch state) + the live within-batch drops: the spill input.
-        tgt_payload = ring.payload.reshape(R * K, -1)[safe_flat]
         ev_rec = jnp.concatenate([safe_rec, safe_rec])
         ev_begin = jnp.concatenate([tgt_begin, beg_s])
         ev_end = jnp.concatenate([tgt_end, end_s])
@@ -298,14 +308,14 @@ def commit_versions(ring: VersionRing, w_rec: jax.Array, w_key: jax.Array,
                 [ins_state, vic_state, drop_state]).astype(jnp.int32),
         }
 
-    begin = begin.reshape(-1).at[flat].set(beg_s, mode="drop").reshape(R, K)
-    end = end.reshape(-1).at[flat].set(end_s, mode="drop").reshape(R, K)
-    payload = ring.payload.reshape(R * K, -1).at[flat].set(
-        data_s, mode="drop").reshape(ring.payload.shape)
+    begin = begin.at[put_rec, slot].set(beg_s, mode="drop")
+    end = end.at[put_rec, slot].set(end_s, mode="drop")
 
     inserted = jnp.zeros((R,), jnp.int32).at[
         jnp.where(w_valid, w_rec, R)].add(1, mode="drop")
-    head = (ring.head + jnp.minimum(inserted, k_arr)) % k_arr
+    # both operands are non-negative (k_eff >= 1), so ``lax.rem`` is the
+    # floored remainder without its sign fix-up
+    head = jax.lax.rem(ring.head + jnp.minimum(inserted, k_arr), k_arr)
 
     new_ring = VersionRing(begin=begin, end=end, payload=payload, head=head)
     occ = ring_occupancy(new_ring)

@@ -32,8 +32,12 @@ Two mapping substrates share one per-shard body:
     loop of kernel calls for resolve) — the layout and arithmetic are
     identical, so sharded state is bit-equal across substrates.
 
-``n_shards == 1`` short-circuits to the plain single-ring code paths on
-the squeezed arrays — bit-identical to the unsharded store.
+The commit maps its per-shard body over the stacked leading axis with
+``vmap`` on every substrate, one shard included, so each leaf is read and
+written in its stored ``[n, ...]`` shape and the donated store is updated
+in place. The read paths short-circuit ``n_shards == 1`` to the plain
+single-ring code on the squeezed arrays — bit-identical to the unsharded
+store.
 """
 from __future__ import annotations
 
@@ -123,7 +127,8 @@ def _with_primary(store: ShardedVersionStore, prim):
 
 
 def _ring0(store: ShardedVersionStore):
-    """The squeezed single primary of an n_shards == 1 store."""
+    """The squeezed single primary of an n_shards == 1 store, for the read
+    paths (the commit never squeezes: it maps over the shard axis)."""
     return jax.tree.map(lambda x: x[0], _primary(store))
 
 
@@ -383,36 +388,17 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
     GLOBAL, rec = -1 where the state is 0/masked) and the
     ``ring_committed`` scalar — all lazy device values; nothing here
     synchronises.
+
+    Every substrate — one shard, logical shards on one device, and each
+    device's shard under ``shard_map`` — runs the per-shard body under
+    ``vmap`` over the stacked leading axis, with no slice and restack of
+    the store: a caller that donates the store (``BohmEngine``'s commit
+    program) gets every leaf updated in place. One shard reports the
+    single ring's metrics as they are.
     """
     n = store.n_shards
     with_spill = store.spill is not None
     paged = store.paged
-    if n == 1:
-        # the shard split and restack serve the stage whose state they
-        # carry: the primary's under commit/ring, the pool's under
-        # commit/spill
-        with jax.named_scope("commit/ring"):
-            prim0 = _ring0(store)
-        with jax.named_scope("commit/spill"):
-            spill_in = _take_spill(store, 0)
-        with jax.named_scope("commit/ring"):
-            k_eff0 = store.k_eff[0]
-        prim, spill0, metrics = _commit_one_shard(
-            prim0, spill_in, k_eff0,
-            w_rec, w_key, w_valid, w_begin_ts, w_end_ts, w_data,
-            watermark, ts_window, pin_ts, with_audit=with_audit)
-        for k in ("ring_overwrote_rec", "ring_overwrote_dead_rec"):
-            metrics[k] = metrics[k][None]
-        if with_audit:
-            metrics["audit_rec"] = jnp.where(
-                metrics["audit_state"] > 0, metrics["audit_rec"], -1)
-        with jax.named_scope("commit/spill"):
-            new_spill = None if spill0 is None else jax.tree.map(
-                lambda x: x[None], spill0)
-        with jax.named_scope("commit/ring"):
-            prim = jax.tree.map(lambda x: x[None], prim)
-        return dataclasses.replace(_with_primary(store, prim),
-                                   spill=new_spill), metrics
 
     def one_shard(prim_s, spill_s, k_eff_s, shard):
         with jax.named_scope("commit/ring"):
@@ -427,20 +413,8 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         from jax.sharding import PartitionSpec as P
 
         def body(prim, spill, k_eff):
-            squeeze = lambda t: jax.tree.map(lambda x: x[0], t)  # noqa: E731
-            with jax.named_scope("commit/ring"):
-                prim_s = squeeze(prim)
-            with jax.named_scope("commit/spill"):
-                spill_s = None if spill is None else squeeze(spill)
-            with jax.named_scope("commit/ring"):
-                k_eff_s = k_eff[0]
-                shard = jax.lax.axis_index(axis)
-            prim_o, spill_o, m = one_shard(prim_s, spill_s, k_eff_s, shard)
-            with jax.named_scope("commit/ring"):
-                prim_o = jax.tree.map(lambda x: x[None], prim_o)
-            with jax.named_scope("commit/spill"):
-                spill_o = jax.tree.map(lambda x: x[None], spill_o)
-            return prim_o, spill_o, jax.tree.map(lambda x: x[None], m)
+            return jax.vmap(one_shard, in_axes=(0, 0, 0, None))(
+                prim, spill, k_eff, jax.lax.axis_index(axis))
 
         out_struct = (_page_struct() if paged else _ring_struct(),
                       None if not with_spill else _spill_struct(),
@@ -456,6 +430,17 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         prim, spill, per = jax.vmap(one_shard)(
             _primary(store), store.spill, store.k_eff,
             jnp.arange(n, dtype=jnp.int32))
+
+    new_store = dataclasses.replace(_with_primary(store, prim), spill=spill)
+    if n == 1:
+        # one shard reports the single ring's metrics as they are
+        metrics = {k: v if k in ("ring_overwrote_rec",
+                                 "ring_overwrote_dead_rec") else v[0]
+                   for k, v in per.items()}
+        if with_audit:
+            metrics["audit_rec"] = jnp.where(
+                metrics["audit_state"] > 0, metrics["audit_rec"], -1)
+        return new_store, metrics
 
     R = store.num_records
     metrics = {
@@ -491,8 +476,7 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         metrics["audit_begin"] = per["audit_begin"].reshape(-1)
         metrics["audit_end"] = per["audit_end"].reshape(-1)
         metrics["audit_state"] = state.reshape(-1)
-    return dataclasses.replace(_with_primary(store, prim),
-                               spill=spill), metrics
+    return new_store, metrics
 
 
 def _ring_struct():

@@ -43,6 +43,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
 from repro.store.ring import INF_TS, pin_stabbed
 
 
@@ -158,22 +159,33 @@ def spill_commit(pool: SpillPool, ev_rec: jax.Array, ev_begin: jax.Array,
     victim_order = jnp.take_along_axis(by_begin, by_prio, axis=1)  # [B, S]
 
     # -- 3. place: evictee with in-bucket rank r -> victim_order[bkt, r] --
+    # the pool is donated to the commit and written in place: its headers
+    # are read and scattered by (bucket, slot) pairs in their stored
+    # [B, S] shape, its payload rows through the in-place row kernel.
+    # Dropped entries read the last slot and write to bucket B, out of
+    # range.
     placed = valid_s & (rank < S)
     slot = victim_order[jnp.minimum(bkt_s, B - 1), jnp.minimum(rank, S - 1)]
-    flat = jnp.where(placed, jnp.minimum(bkt_s, B - 1) * S + slot, B * S)
-    safe = jnp.minimum(flat, B * S - 1)
-    victim_occ = placed & (pool.rec.reshape(-1)[safe] >= 0)
-    victim_pinned = placed & pinned.reshape(-1)[safe]
+    put_bkt = jnp.where(placed, jnp.minimum(bkt_s, B - 1), B)
+    get_bkt = jnp.where(placed, put_bkt, B - 1)
+    get_slot = jnp.where(placed, slot, S - 1)
+    v_rec = pool.rec[get_bkt, get_slot]
+    v_begin = pool.begin[get_bkt, get_slot]
+    v_end = pool.end[get_bkt, get_slot]
+    victim_occ = placed & (v_rec >= 0)
+    victim_pinned = placed & pinned[get_bkt, get_slot]
 
     def scatter(dst, src):
-        flat_dst = dst.reshape((B * S,) + dst.shape[2:])
-        return flat_dst.at[flat].set(src, mode="drop").reshape(dst.shape)
+        return dst.at[put_bkt, slot].set(src, mode="drop")
 
+    payload, _ = ops.update_rows(pool.payload, jnp.minimum(bkt_s, B - 1),
+                                 slot, placed, ev_payload[order],
+                                 with_old=False)
     new_pool = SpillPool(
         begin=scatter(pool.begin, ev_begin[order]),
         end=scatter(pool.end, ev_end[order]),
         rec=scatter(pool.rec, ev_rec[order]),
-        payload=scatter(pool.payload, ev_payload[order]))
+        payload=payload)
 
     metrics = {
         "spill_freed": freed,
@@ -194,9 +206,6 @@ def spill_commit(pool: SpillPool, ev_rec: jax.Array, ev_begin: jax.Array,
             init = jnp.full((Ne,), fill, sorted_vals.dtype)
             return init.at[order].set(sorted_vals)
 
-        v_rec = pool.rec.reshape(-1)[safe]
-        v_begin = pool.begin.reshape(-1)[safe]
-        v_end = pool.end.reshape(-1)[safe]
         metrics.update(
             spill_audit_placed=to_input(placed, False),
             spill_victim_valid=to_input(victim_occ, False),

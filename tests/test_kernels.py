@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 INF = np.iinfo(np.int32).max
 
@@ -139,3 +139,61 @@ def test_flash_kernel_matches_model_path():
     np.testing.assert_allclose(np.asarray(o_model),
                                np.asarray(o_kern.reshape(b, s, -1, dh)),
                                rtol=1e-4, atol=1e-4)
+
+
+# -- in-place version-row writes (the commit's ring and spill payloads) ----
+def _row_updates(rng, r, k, d, n):
+    """Targets distinct among the kept entries, as a commit's are; the
+    dropped ones point anywhere."""
+    flat = rng.choice(r * k, min(n, r * k), replace=False)
+    flat = np.resize(flat, n)
+    keep = (rng.random(n) < 0.7) & (np.arange(n) < r * k)
+    rec = np.where(keep, flat // k, rng.integers(0, r, n))
+    return (jnp.asarray(rec, jnp.int32), jnp.asarray(flat % k, jnp.int32),
+            jnp.asarray(keep),
+            jnp.asarray(rng.integers(-2**31, 2**31 - 1, (n, d)), jnp.int32))
+
+
+# the kernel's two record-minor views, and the row-major layout on which
+# XLA's own scatter writes in place (the CPU's)
+ROW_VIEWS = {"slots_on_sublanes": (2, 1, 0), "words_on_sublanes": (1, 2, 0),
+             "row_major": (0, 1, 2)}
+
+
+@pytest.mark.parametrize("view", sorted(ROW_VIEWS))
+@pytest.mark.parametrize("r,k,d,n", [(1000, 4, 5, 300), (512, 8, 3, 200),
+                                     (300, 4, 250, 64), (256, 4, 2, 600),
+                                     (64, 4, 8, 40)])
+@pytest.mark.parametrize("with_old", [True, False])
+def test_update_rows(view, r, k, d, n, with_old, monkeypatch):
+    from repro.kernels import row_update
+    monkeypatch.setattr(row_update, "stored_order",
+                        lambda shape, dtype: ROW_VIEWS[view])
+    rng = np.random.default_rng(r + k + d + n)
+    x = jnp.asarray(rng.integers(-2**31, 2**31 - 1, (r, k, d)), jnp.int32)
+    rec, slot, keep, rows = _row_updates(rng, r, k, d, n)
+    want, want_old = ref.update_rows_ref(x, rec, slot, keep, rows)
+    got, old = jax.jit(lambda x: ops.update_rows(
+        x, rec, slot, keep, rows, with_old=with_old), donate_argnums=0)(x)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    if with_old:
+        np.testing.assert_array_equal(np.asarray(old), np.asarray(want_old))
+    else:
+        assert old is None
+
+
+@pytest.mark.parametrize("view", sorted(ROW_VIEWS))
+def test_update_rows_per_shard(view, monkeypatch):
+    """vmapped over stacked shards, as the sharded commit maps it."""
+    from repro.kernels import row_update
+    monkeypatch.setattr(row_update, "stored_order",
+                        lambda shape, dtype: ROW_VIEWS[view])
+    rng = np.random.default_rng(7)
+    n_sh, r, k, d, n = 3, 130, 4, 3, 100
+    x = jnp.asarray(rng.integers(0, 1000, (n_sh, r, k, d)), jnp.int32)
+    upd = [_row_updates(rng, r, k, d, n) for _ in range(n_sh)]
+    rec, slot, keep, rows = (jnp.stack(a) for a in zip(*upd))
+    got, old = jax.vmap(ops.update_rows)(x, rec, slot, keep, rows)
+    want, want_old = jax.vmap(ref.update_rows_ref)(x, rec, slot, keep, rows)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(old), np.asarray(want_old))

@@ -1,4 +1,5 @@
-"""Compile the snapshot-read path for a described TPU v5e, no chip needed.
+"""Compile the snapshot-read path and the commit for a described TPU v5e,
+no chip needed.
 
 Interpret mode (every other test file) cannot see what Mosaic refuses:
 unaligned blocks, unsupported boolean reshapes, more fast memory than a
@@ -7,13 +8,16 @@ kernel may use. These tests lower the resolve kernels natively
 topology and check that a Mosaic kernel (``tpu_custom_call``) is in the
 compiled program: the two kernels at the read counts the engine sends,
 and the engine's whole read-only resolve step over a 1,000,000-record
-store — dense ring + spill, and paged slab + spill.
+store — dense ring + spill, and paged slab + spill. The commit program
+is checked for what the chip's layouts make of it: the row kernel in
+it, and no relayout of a whole ring or spill payload.
 
 The topology is described inside a module fixture (never at import):
 only one process may hold the TPU library, so the test workers must all
 collect the same tests and only the worker running this file loads it.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -107,3 +111,72 @@ def test_store_resolve_compiles_for_v5e(one_chip, monkeypatch, paged):
     reads = jax.ShapeDtypeStruct((10240,), jnp.int32, sharding=one_chip)
     _assert_mosaic(jax.jit(resolve_sharded).lower(
         _spec(store, one_chip), reads, reads))
+
+
+# the commit's store shapes: the engine's default ring (K = 4) and a spill
+# pool of 8-slot buckets at the 1 KB record of ``ycsb-1kb`` and the 8-byte
+# record of ``micro-8b``, with 2^16 records (the layouts, and so the
+# program's ops, are those of the full-size stores). R/8 buckets, not the
+# engine's R/4: at 8 bytes an R/4 pool's payload has as many elements as
+# the ring's [R, 4] headers, whose small relayouts remain.
+@pytest.mark.parametrize("d", [250, 2])
+def test_commit_writes_store_in_place_on_v5e(one_chip, monkeypatch, d):
+    """The engine's commit program, compiled for the chip: the store is
+    donated and aliased to the outputs, the payload rows go through the
+    row kernel in the stored record-minor layout, and no copy, relayout,
+    pad or concatenate rebuilds a whole ring or spill payload."""
+    from repro.core.engine import commit_phase, exec_phase, plan_phase
+    from repro.core.execute import init_store
+    from repro.core.workloads import gen_ycsb_batch, make_ycsb
+    from repro.kernels import row_update
+    from repro.runtime import jit_named
+
+    def stored_order(shape, dtype):
+        spec = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        fmt = jax.jit(lambda a: a).lower(spec).compile().input_formats
+        return tuple(fmt[0][0].layout.major_to_minor)
+
+    monkeypatch.setattr(row_update, "default_interpret", lambda: False)
+    monkeypatch.setattr(row_update, "stored_order", stored_order)
+    r, t = 1 << 16, 1024
+    store = jax.eval_shape(lambda: init_store(
+        r, d, ring_slots=4, spill_buckets=r // 8, spill_slots=8, k_init=4))
+    batch = gen_ycsb_batch(np.random.default_rng(0), t, r, theta=0.9,
+                           mix="2rmw8r")
+    plan = jax.eval_shape(lambda b: plan_phase(b, jnp.int32(1), mesh=None,
+                                               cc_axis="cc"), batch)
+    w_data = jax.eval_shape(
+        lambda p, b, s: exec_phase(p, b, s,
+                                   workload=make_ycsb(payload_words=d,
+                                                      ops=10)),
+        plan, batch, store)[0]
+    ts = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    pins = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    commit = jit_named(commit_phase, donate_argnums=(2,), mesh=None,
+                       cc_axis="cc")
+    compiled = commit.lower(
+        _spec(plan, one_chip), _spec(batch, one_chip),
+        _spec(store, one_chip), _spec(w_data, one_chip), ts, (ts, ts),
+        pins).compile()
+    text = compiled.as_text()
+    assert text.splitlines()[0].startswith("HloModule jit_commit_phase,")
+    assert "tpu_custom_call" in text
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        np.prod(x.shape) * x.dtype.itemsize for x in jax.tree.leaves(store)
+        if x is not store.versions.k_eff)
+    payloads = [store.versions.rings.payload, store.versions.spill.payload]
+    sizes = {int(np.prod(x.shape)) for x in payloads}
+    # a header leaf can have a payload's element count (the 8-byte record)
+    others = {tuple(x.shape) for x in jax.tree.leaves(store)
+              if all(x is not p for p in payloads)}
+    # no relayout (``copy``, ``transpose``, ``reshape``), ``pad`` or
+    # ``concatenate`` of a whole payload; the in-place passes are fusions
+    # and the kernels custom calls, and ``copy-start``/``copy-done`` pairs
+    # move an array between memories without changing its layout
+    entry = text[text.index("\nENTRY"):]
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* ([\w-]+)\(", entry):
+        dims = tuple(int(v) for v in m.group(1).split(",") if v)
+        if int(np.prod(dims)) in sizes and dims not in others:
+            assert m.group(2) in (
+                "bitcast", "parameter", "fusion", "get-tuple-element",
+                "custom-call", "copy-start", "copy-done"), m.group(0)
