@@ -46,7 +46,7 @@ import numpy as np
 
 from repro.core import plan as plan_mod
 from repro.core.execute import (Store, commit, execute_plan, init_store,
-                                place_store, store_from_base)
+                                store_from_base)
 from repro.core.plan import MAX_BATCH_TXNS, Plan, cc_plan
 from repro.core.txn import TxnBatch, Workload
 from repro.obs import MetricsRegistry, PhaseTracer, engine_health
@@ -186,8 +186,7 @@ class BohmEngine:
                                 k_init=ring_slots, paged=self.paged,
                                 page_slots=self.page_slots or 4,
                                 pages_per_shard=self.pages_per_shard
-                                or None)
-        self.store = place_store(self.store, mesh, cc_axis)
+                                or None, mesh=mesh, axis=cc_axis)
         self._ts_next = 1                  # host mirror of store.ts_counter
         self._snapshots: Dict[int, SnapshotHandle] = {}
         self._next_sid = 0
@@ -226,6 +225,8 @@ class BohmEngine:
 
     _SPILL_KEYS = ("spill_admitted", "spill_dropped",
                    "spill_overwrote_pinned")
+    # versions committed, and the busiest shard's, summed over epochs
+    _SHARD_KEYS = ("shard_writes", "shard_writes_max")
 
     def _declare_metrics(self) -> None:
         """(Re)declare the engine's device counters on the registry —
@@ -239,7 +240,7 @@ class BohmEngine:
         m.declare("engine/ring_overwrote_dead_rec", k_eff)
         for name in ("ring_overwrote_live", "ring_overwrote_dead",
                      "paged_alloc_failed", "aborts", "waves",
-                     *self._SPILL_KEYS):
+                     *self._SPILL_KEYS, *self._SHARD_KEYS):
             m.declare(f"engine/{name}", scalar)
         m.set("engine/commits", 0)
         m.set("engine/txns_committed", 0)
@@ -302,7 +303,10 @@ class BohmEngine:
     def reset_store(self, base: jax.Array,
                     base_ts: Optional[jax.Array] = None) -> None:
         """Reinitialise committed state (head cache + rings + spill) from
-        ``base`` (copied: the caller's array stays its own)."""
+        ``base`` (copied: the caller's array stays its own). The old store
+        is let go before the new one is built, so the two are never on
+        the devices together."""
+        self.store = None
         self.store = store_from_base(base, base_ts, self.k_max,
                                      self.n_shards,
                                      spill_buckets=self.spill_buckets,
@@ -311,8 +315,8 @@ class BohmEngine:
                                      paged=self.paged,
                                      page_slots=self.page_slots or 4,
                                      pages_per_shard=self.pages_per_shard
-                                     or None)
-        self.store = place_store(self.store, self.mesh, self.cc_axis)
+                                     or None, mesh=self.mesh,
+                                     axis=self.cc_axis)
         self._ts_next = 1
         self._snapshots.clear()
         self._declare_metrics()
@@ -550,7 +554,7 @@ class BohmEngine:
         for key in ("ring_overwrote_rec", "ring_overwrote_dead_rec",
                     "ring_overwrote_live", "ring_overwrote_dead",
                     "paged_alloc_failed", "aborts", "waves",
-                    *self._SPILL_KEYS):
+                    *self._SPILL_KEYS, *self._SHARD_KEYS):
             if key in metrics:
                 m.accumulate(f"engine/{key}", metrics[key])
         m.inc("engine/commits")

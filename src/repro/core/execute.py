@@ -57,18 +57,18 @@ def init_store(num_records: int, payload_words: int,
                spill_slots: int = 0,
                k_init: Optional[int] = None,
                paged: bool = False, page_slots: int = 4,
-               pages_per_shard: Optional[int] = None) -> Store:
-    base = jnp.full((num_records, payload_words), init_value, jnp.int32)
-    base_ts = jnp.zeros((num_records,), jnp.int32)
-    return Store(
-        base=base, base_ts=base_ts,
-        ts_counter=jnp.ones((), jnp.int32),
-        versions=init_sharded_store(base, base_ts, ring_slots, n_shards,
-                                    spill_buckets=spill_buckets,
-                                    spill_slots=spill_slots,
-                                    k_init=k_init, paged=paged,
-                                    page_slots=page_slots,
-                                    pages_per_shard=pages_per_shard))
+               pages_per_shard: Optional[int] = None,
+               mesh=None, axis: str = "cc") -> Store:
+    """Store whose every record holds ``init_value`` (head + ring slot
+    0), laid out on ``mesh`` as ``store_from_base`` says."""
+    def build():
+        base = jnp.full((num_records, payload_words), init_value, jnp.int32)
+        base_ts = jnp.zeros((num_records,), jnp.int32)
+        return _store(base, base_ts, ring_slots, n_shards,
+                      spill_buckets=spill_buckets, spill_slots=spill_slots,
+                      k_init=k_init, paged=paged, page_slots=page_slots,
+                      pages_per_shard=pages_per_shard)
+    return _build_on(mesh, axis, n_shards, build)
 
 
 def store_from_base(base: jax.Array, base_ts: Optional[jax.Array] = None,
@@ -76,38 +76,49 @@ def store_from_base(base: jax.Array, base_ts: Optional[jax.Array] = None,
                     spill_buckets: int = 0, spill_slots: int = 0,
                     k_init: Optional[int] = None,
                     paged: bool = False, page_slots: int = 4,
-                    pages_per_shard: Optional[int] = None) -> Store:
+                    pages_per_shard: Optional[int] = None,
+                    mesh=None, axis: str = "cc") -> Store:
     """Store whose initial state (head + ring slot 0) is ``base``. The head
     is a copy of ``base`` (and of ``base_ts``): the commit updates the
-    store's arrays in place, which must not reach the caller's."""
-    base = jnp.array(base, jnp.int32, copy=True)
-    base_ts = (jnp.zeros((base.shape[0],), jnp.int32) if base_ts is None
-               else jnp.array(base_ts, jnp.int32, copy=True))
+    store's arrays in place, which must not reach the caller's.
+
+    With a ``mesh`` whose ``axis`` has ``n_shards`` devices, the store is
+    built on it: each device computes only its own shards' rings, page
+    slabs, spill pools and ``k_eff`` (every [n, R/n, ...] version leaf
+    split over ``axis``), and the head, ``base_ts`` and the ts counter
+    are replicated. No device ever holds the whole version store. Any
+    other mesh (or none) builds the store on the default device."""
+    def build(base, base_ts):
+        base = jnp.array(base, jnp.int32, copy=True)
+        base_ts = (jnp.zeros((base.shape[0],), jnp.int32) if base_ts is None
+                   else jnp.array(base_ts, jnp.int32, copy=True))
+        return _store(base, base_ts, ring_slots, n_shards,
+                      spill_buckets=spill_buckets, spill_slots=spill_slots,
+                      k_init=k_init, paged=paged, page_slots=page_slots,
+                      pages_per_shard=pages_per_shard)
+    return _build_on(mesh, axis, n_shards, build, base, base_ts)
+
+
+def _store(base, base_ts, ring_slots, n_shards, **versions) -> Store:
     return Store(base=base, base_ts=base_ts,
                  ts_counter=jnp.ones((), jnp.int32),
                  versions=init_sharded_store(base, base_ts, ring_slots,
-                                             n_shards,
-                                             spill_buckets=spill_buckets,
-                                             spill_slots=spill_slots,
-                                             k_init=k_init, paged=paged,
-                                             page_slots=page_slots,
-                                             pages_per_shard=pages_per_shard))
+                                             n_shards, **versions))
 
 
-def place_store(store: Store, mesh, axis: str = "cc") -> Store:
-    """Lay ``store`` out on ``mesh``: every [n, R/n, ...] leaf of the
-    version store splits over ``axis`` (each device holds its own
-    shards' rings / pages / spill pool), the head cache and the ts
-    counter replicate. A store whose shard count is not the axis size
-    (or no mesh) stays where it is."""
-    if mesh is None or mesh.shape.get(axis) != store.versions.n_shards:
-        return store
+def _build_on(mesh, axis: str, n_shards: int, build, *args) -> Store:
+    """``build(*args)`` eagerly on the default device, or, where ``mesh``
+    splits ``axis`` into ``n_shards`` devices, as one program whose
+    outputs are made where they live: the version leaves split over
+    ``axis``, the rest replicated (the arguments are replicated first)."""
+    if mesh is None or mesh.shape.get(axis) != n_shards:
+        return build(*args)
     split = NamedSharding(mesh, P(axis))
     whole = NamedSharding(mesh, P())
-    return Store(base=jax.device_put(store.base, whole),
-                 base_ts=jax.device_put(store.base_ts, whole),
-                 ts_counter=jax.device_put(store.ts_counter, whole),
-                 versions=jax.device_put(store.versions, split))
+    shape = jax.eval_shape(build, *args)
+    out = Store(base=whole, base_ts=whole, ts_counter=whole,
+                versions=jax.tree.map(lambda _: split, shape.versions))
+    return jax.jit(build, out_shardings=out)(*jax.device_put(args, whole))
 
 
 def execute_plan(plan: Plan, batch: TxnBatch, store: Store,

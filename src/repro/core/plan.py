@@ -134,15 +134,19 @@ def cc_plan_sharded(batch: TxnBatch, ts_base: jax.Array, mesh,
     n = mesh.shape[axis]
 
     def shard_fn(read_set, write_set, txn_type, args, ts_b):
-        shard = jax.lax.axis_index(axis)
-        # mask write/read records not owned by this shard (hash partition)
-        owned_w = (write_set % n) == shard
-        owned_r = (read_set % n) == shard
-        local = TxnBatch(jnp.where(owned_r & (read_set >= 0), read_set, -1),
-                         jnp.where(owned_w & (write_set >= 0), write_set, -1),
-                         txn_type, args)
-        p = cc_plan(local, ts_b)
-        return jax.tree.map(lambda x: x[None], p)   # add shard axis
+        # the shard's own CC work, named on the device trace's ops
+        with jax.named_scope("plan/shard"):
+            shard = jax.lax.axis_index(axis)
+            # mask write/read records not owned by this shard (hash
+            # partition)
+            owned_w = (write_set % n) == shard
+            owned_r = (read_set % n) == shard
+            local = TxnBatch(
+                jnp.where(owned_r & (read_set >= 0), read_set, -1),
+                jnp.where(owned_w & (write_set >= 0), write_set, -1),
+                txn_type, args)
+            p = cc_plan(local, ts_b)
+            return jax.tree.map(lambda x: x[None], p)   # add shard axis
 
     from jax.sharding import PartitionSpec as P
     fn = jax.shard_map(
@@ -307,26 +311,29 @@ def merge_sharded_plan(plan: Plan, batch: TxnBatch) -> Plan:
     Per-shard slots index into per-shard version arrays; execution uses
     (shard, slot) pairs encoded as shard * Nw + slot. Reads/writes merge by
     maximum (each entry is owned by exactly one shard; others hold -1/pads).
+    On a mesh this is the exchange between the shards' plans; its ops are
+    named ``plan/merge``.
     """
-    n = plan.w_rec.shape[0]
-    Nw = plan.w_rec.shape[1]
-    off = (jnp.arange(n, dtype=jnp.int32) * Nw)[:, None]
+    with jax.named_scope("plan/merge"):
+        n = plan.w_rec.shape[0]
+        Nw = plan.w_rec.shape[1]
+        off = (jnp.arange(n, dtype=jnp.int32) * Nw)[:, None]
 
-    def enc(slot2d):
-        return jnp.where(slot2d >= 0, slot2d + off.reshape(
-            (n,) + (1,) * (slot2d.ndim - 1)), -1)
+        def enc(slot2d):
+            return jnp.where(slot2d >= 0, slot2d + off.reshape(
+                (n,) + (1,) * (slot2d.ndim - 1)), -1)
 
-    w_slot = jnp.max(enc(plan.w_slot), axis=0)
-    r_dep_slot = jnp.max(enc(plan.r_dep_slot), axis=0)
-    r_dep_txn = jnp.max(plan.r_dep_txn, axis=0)
-    return Plan(
-        w_rec=plan.w_rec.reshape(-1),
-        w_txn=plan.w_txn.reshape(-1),
-        w_end_local=plan.w_end_local.reshape(-1),
-        w_valid=plan.w_valid.reshape(-1),
-        w_key=plan.w_key.reshape(-1),
-        w_slot=w_slot, r_dep_txn=r_dep_txn, r_dep_slot=r_dep_slot,
-        commit_mask=plan.commit_mask.reshape(-1),
-        ts_base=plan.ts_base.reshape(-1)[0],
-        w_begin_ts=plan.w_begin_ts.reshape(-1),
-        w_end_ts=plan.w_end_ts.reshape(-1))
+        w_slot = jnp.max(enc(plan.w_slot), axis=0)
+        r_dep_slot = jnp.max(enc(plan.r_dep_slot), axis=0)
+        r_dep_txn = jnp.max(plan.r_dep_txn, axis=0)
+        return Plan(
+            w_rec=plan.w_rec.reshape(-1),
+            w_txn=plan.w_txn.reshape(-1),
+            w_end_local=plan.w_end_local.reshape(-1),
+            w_valid=plan.w_valid.reshape(-1),
+            w_key=plan.w_key.reshape(-1),
+            w_slot=w_slot, r_dep_txn=r_dep_txn, r_dep_slot=r_dep_slot,
+            commit_mask=plan.commit_mask.reshape(-1),
+            ts_base=plan.ts_base.reshape(-1)[0],
+            w_begin_ts=plan.w_begin_ts.reshape(-1),
+            w_end_ts=plan.w_end_ts.reshape(-1))

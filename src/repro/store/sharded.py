@@ -395,6 +395,10 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
     the store: a caller that donates the store (``BohmEngine``'s commit
     program) gets every leaf updated in place. One shard reports the
     single ring's metrics as they are.
+
+    ``shard_writes`` counts the batch versions the shards commit (each
+    shard's owned writes) and ``shard_writes_max`` the busiest shard's;
+    one shard reports the two equal.
     """
     n = store.n_shards
     with_spill = store.spill is not None
@@ -404,10 +408,13 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         with jax.named_scope("commit/ring"):
             rec_l, key_l, owned = _mask_to_shard(n, shard, w_rec, w_key,
                                                  w_valid)
-        return _commit_one_shard(prim_s, spill_s, k_eff_s, rec_l, key_l,
-                                 owned, w_begin_ts, w_end_ts, w_data,
-                                 watermark, ts_window, pin_ts,
-                                 with_audit=with_audit)
+            writes = jnp.sum(owned, dtype=jnp.int32)
+        prim_o, spill_o, m = _commit_one_shard(
+            prim_s, spill_s, k_eff_s, rec_l, key_l, owned, w_begin_ts,
+            w_end_ts, w_data, watermark, ts_window, pin_ts,
+            with_audit=with_audit)
+        m["shard_writes"] = writes
+        return prim_o, spill_o, m
 
     if mesh is not None and axis in mesh.shape and mesh.shape[axis] == n:
         from jax.sharding import PartitionSpec as P
@@ -437,6 +444,7 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         metrics = {k: v if k in ("ring_overwrote_rec",
                                  "ring_overwrote_dead_rec") else v[0]
                    for k, v in per.items()}
+        metrics["shard_writes_max"] = metrics["shard_writes"]
         if with_audit:
             metrics["audit_rec"] = jnp.where(
                 metrics["audit_state"] > 0, metrics["audit_rec"], -1)
@@ -455,6 +463,11 @@ def commit_sharded(store: ShardedVersionStore, w_rec: jax.Array,
         # renormalise to the real record count
         "ring_occ_mean": jnp.sum(per["ring_occ_mean"])
         * store.records_per_shard / R,
+        # the partition's load: versions committed, and the busiest
+        # shard's share of them (hash-partitioned zipf keys load the
+        # shards unevenly)
+        "shard_writes": jnp.sum(per["shard_writes"]),
+        "shard_writes_max": jnp.max(per["shard_writes"]),
     }
     if paged:
         for k in ("paged_alloc_failed", "paged_pages_allocated",
@@ -500,7 +513,7 @@ def _metrics_struct(with_spill: bool = False, paged: bool = False,
     m = {"ring_evicted": z, "ring_overflow_dropped": z,
          "ring_overwrote_live": z, "ring_overwrote_dead": z,
          "ring_overwrote_rec": z, "ring_overwrote_dead_rec": z,
-         "ring_occ_max": z, "ring_occ_mean": z}
+         "ring_occ_max": z, "ring_occ_mean": z, "shard_writes": z}
     if paged:
         m.update({"paged_alloc_failed": z, "paged_pages_allocated": z,
                   "paged_pages_free": z})
